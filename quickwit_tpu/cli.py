@@ -45,22 +45,57 @@ def _embedded_node(args) -> Node:
     return Node(config, storage_resolver=_resolver())
 
 
+def _device_report() -> dict[str, Any]:
+    """What JAX runs this node on, as JAX reports it. Initialises the
+    backend: from here on this process owns the chip."""
+    import jax
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
+def _device_memory_report() -> list[dict[str, Any]]:
+    """Per device: the bytes of the array shards it holds right now, and
+    the allocator's peak and limit (None where the backend reports no
+    memory stats, as the CPU backend does)."""
+    import jax
+    live = {device.id: 0 for device in jax.devices()}
+    for array in jax.live_arrays():
+        for shard in array.addressable_shards:
+            live[shard.device.id] += shard.data.nbytes
+    report = []
+    for device in jax.devices():
+        stats = device.memory_stats() or {}
+        report.append({"id": device.id, "live_bytes": live[device.id],
+                       "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+                       "bytes_limit": stats.get("bytes_limit")})
+    return report
+
+
 def cmd_run(args) -> int:
+    from .native import load_fastindex
     from .serve.rest import RestServer
     config = load_node_config(args.config)
     node = Node(config, storage_resolver=_resolver())
+    # flushed line by line: a supervisor reading a redirected stdout must
+    # see the device and the endpoint before the first request, not at exit
+    print(f"node {config.node_id} devices: {json.dumps(_device_report())} "
+          f"native_indexer={load_fastindex() is not None}", flush=True)
     server = RestServer(node)
     server.start()
     node.start_background_services()
     print(f"node {config.node_id} (roles: {','.join(config.roles)}) "
           f"listening on "
-          f"{'https' if config.tls_enabled else 'http'}://{server.endpoint}")
+          f"{'https' if config.tls_enabled else 'http'}://{server.endpoint}",
+          flush=True)
     try:
         while True:
             time.sleep(3600)
     except KeyboardInterrupt:
         node.stop_background_services()
         server.stop()
+        print(f"node {config.node_id} stopped; device memory: "
+              f"{json.dumps(_device_memory_report())}", flush=True)
     return 0
 
 

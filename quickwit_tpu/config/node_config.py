@@ -89,6 +89,7 @@ def load_node_config(path: Optional[str] = None,
         rest_port=int(environ.get("QW_REST_PORT",
                                   rest.get("listen_port", 7280))),
         peers=tuple(data.get("peer_seeds", ())),
+        data_dir=data.get("data_dir"),
         tls_cert_path=tls.get("cert_path"),
         tls_key_path=tls.get("key_path"),
         tls_ca_path=tls.get("ca_path"),

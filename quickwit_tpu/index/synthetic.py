@@ -10,7 +10,6 @@ benchmarks exercise the real search stack.
 
 from __future__ import annotations
 
-import json
 import zlib
 from typing import Optional
 
@@ -437,14 +436,41 @@ def synthetic_otel_split(num_docs: int, seed: int = 0,
     return builder.finish(footer)
 
 
+# docs per doc-store block: ~64 KiB of JSON lines, the writer's block size
+_STORE_BLOCK_DOCS = 1024
+
+
 def _write_store(builder, ts_seconds, tenants, sev, num_docs):
-    lines = []
-    for i in range(num_docs):
-        lines.append(json.dumps({
-            "timestamp": int(ts_seconds[i]), "tenant_id": int(tenants[i]),
-            "severity_text": SEVERITIES[int(sev[i])]},
-            separators=(",", ":")).encode())
-    block = zlib.compress(b"\n".join(lines), 1)
-    builder.add_array("store.data", np.frombuffer(block, dtype=np.uint8))
-    builder.add_array("store.block_offsets", np.array([0, len(block)], dtype=np.int64))
-    builder.add_array("store.block_first_doc", np.array([0, num_docs], dtype=np.int32))
+    """Blocked doc store, vectorized: every doc is one fixed-width JSON
+    line (trailing spaces pad the shorter severities; JSON ignores them),
+    so the whole store is one [num_docs, width] byte matrix cut into
+    `_STORE_BLOCK_DOCS`-doc zlib blocks — a fetch decompresses one block,
+    and a 10M-doc store builds in seconds. The body is not stored."""
+    if int(ts_seconds.max()) >= 10**10 or int(tenants.max()) >= 10:
+        raise ValueError("fixed-width store needs 10-digit timestamps and "
+                         "1-digit tenant ids")
+    sev_width = max(len(s) for s in SEVERITIES)
+    pieces = (b'{"timestamp":', b"0" * 10, b',"tenant_id":', b"0",
+              b',"severity_text":"', b" " * (sev_width + 2), b"\n")
+    template = np.frombuffer(b"".join(pieces), dtype=np.uint8)
+    rows = np.tile(template, (num_docs, 1))
+    ts_at = len(pieces[0])
+    tenant_at = ts_at + 10 + len(pieces[2])
+    sev_at = tenant_at + 1 + len(pieces[4])
+    ts = ts_seconds.astype(np.int64)
+    for i in range(10):
+        rows[:, ts_at + i] = (ts // 10 ** (9 - i)) % 10 + ord("0")
+    rows[:, tenant_at] = tenants + ord("0")
+    tails = np.array([list((s + '"}').ljust(sev_width + 2).encode())
+                      for s in SEVERITIES], dtype=np.uint8)
+    rows[:, sev_at: sev_at + sev_width + 2] = tails[sev]
+    first_docs = np.arange(0, num_docs, _STORE_BLOCK_DOCS, dtype=np.int64)
+    blocks = [zlib.compress(rows[f: f + _STORE_BLOCK_DOCS].tobytes(), 1)
+              for f in first_docs]
+    offsets = np.zeros(len(blocks) + 1, dtype=np.int64)
+    np.cumsum([len(b) for b in blocks], out=offsets[1:])
+    builder.add_array("store.data",
+                      np.frombuffer(b"".join(blocks), dtype=np.uint8))
+    builder.add_array("store.block_offsets", offsets)
+    builder.add_array("store.block_first_doc",
+                      np.append(first_docs, num_docs).astype(np.int32))

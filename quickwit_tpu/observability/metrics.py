@@ -477,9 +477,9 @@ SEARCH_CANCEL_TOTAL = METRICS.counter(
 
 # --- multi-chip collective root merge (parallel/fanout.py mesh path) --------
 # One dispatch = one whole-query shard_map program: per-device split shards
-# score locally, exchange the running sort-value threshold (pmax), merge
-# top-K (all_gather + re-top-k) and mergeable agg states (psum/pmin/pmax)
-# on-mesh, and read back ONE packed scalar array.
+# score locally, exchange the running sort-value threshold (all-reduce
+# max), merge top-K (all_gather + re-top-k) and mergeable agg states
+# (psum/min/max) on-mesh, and read back ONE packed scalar array.
 MESH_DISPATCHES_TOTAL = METRICS.counter(
     "qw_mesh_dispatches_total",
     "Whole-query collective programs dispatched over a device mesh")
@@ -487,15 +487,16 @@ MESH_DEVICES = METRICS.gauge(
     "qw_mesh_devices",
     "Devices (splits axis x docs axis) of the most recent mesh dispatch")
 # Logical payload bytes, not wire bytes: each collective's operand size
-# summed once per dispatch (all_gather candidates + psum/pmin/pmax agg,
-# count, and certificate payloads + the threshold-exchange scalar). Wire
+# summed once per dispatch (all_gather candidates + psum/min/max agg,
+# count, and certificate payloads + the threshold-exchange scalar; a
+# 64-bit max/min is an all_gather and counts once per device). Wire
 # amplification is topology-dependent and deliberately out of scope.
 MESH_COLLECTIVE_BYTES_TOTAL = METRICS.counter(
     "qw_mesh_collective_bytes_total",
     "Logical payload bytes moved by on-mesh collectives per dispatch")
 MESH_THRESHOLD_EXCHANGE_ROUNDS_TOTAL = METRICS.counter(
     "qw_mesh_threshold_exchange_rounds_total",
-    "Cross-device sort-threshold all-reduce (pmax) rounds executed")
+    "Cross-device sort-threshold all-reduce (max) rounds executed")
 
 # --- flight recorder (observability/flight.py) ------------------------------
 # The always-on device-timeline black box: typed lifecycle events from

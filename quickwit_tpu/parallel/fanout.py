@@ -309,15 +309,20 @@ def _build_batch(request: SearchRequest, doc_mapper: DocMapper,
 _BATCH_JIT_CACHE: dict[tuple, Any] = {}
 
 
+def _agg_leaf_kind(path) -> Optional[str]:
+    """The innermost dict key on a merged-agg leaf's tree path — what
+    decides its combiner (min / max / hll / stats / sum)."""
+    for p in reversed(path):
+        if hasattr(p, "key"):
+            return p.key
+    return None
+
+
 def _merge_agg_stack(agg_out):
     """agg_out leaves carry a leading split axis [n, ...] → reduce axis 0
     (counts/sums add, min/max combine by leaf name)."""
     def red(path, leaf):
-        name = None
-        for p in reversed(path):
-            if hasattr(p, "key"):
-                name = p.key
-                break
+        name = _agg_leaf_kind(path)
         if name == "min":
             return jnp.min(leaf, axis=0)
         if name in ("max", "hll"):  # HLL registers merge by max too
@@ -415,11 +420,27 @@ def _usable_mesh(batch: SplitBatch, mesh: Optional[Mesh]) -> Optional[Mesh]:
     return mesh if batch.n_splits % mesh.shape[split_ax] == 0 else None
 
 
+def _all_reduce_extremum(x, axis_name: str, op: str):
+    """Cross-device `max`/`min` over a mesh axis, replicated on every
+    device. The TPU compiler lowers only *sum* all-reduces on 64-bit
+    element types (f64/i64 are emulated there; a 64-bit pmax/pmin is
+    refused with UNIMPLEMENTED), so 64-bit operands `all_gather` along the
+    axis and reduce locally — the same values under the same total order,
+    hence bit-identical to pmax/pmin and to the host merge. Narrower
+    dtypes keep the native all-reduce."""
+    from jax import lax
+    if x.dtype.itemsize < 8:
+        return (lax.pmax if op == "max" else lax.pmin)(x, axis_name)
+    gathered = lax.all_gather(x, axis_name)          # [axis_size, ...]
+    return (jnp.max if op == "max" else jnp.min)(gathered, axis=0)
+
+
 def _merge_agg_collective(agg_out, split_ax: str):
     """`_merge_agg_stack`'s collective twin: the local [local_n, ...] stack
     reduces over axis 0 on each device, then the SAME per-leaf combiner
-    runs once more across the split mesh axis (psum / pmin / pmax), so the
-    merged states land replicated on every device — no host merge.
+    runs once more across the split mesh axis (psum /
+    `_all_reduce_extremum`), so the merged states land replicated on every
+    device — no host merge.
 
     Exactness: counts, bucket tallies, and HLL registers are integral-
     valued, so f64 reduction re-association cannot change them; float
@@ -429,21 +450,21 @@ def _merge_agg_collective(agg_out, split_ax: str):
     from jax import lax
 
     def red(path, leaf):
-        name = None
-        for p in reversed(path):
-            if hasattr(p, "key"):
-                name = p.key
-                break
+        name = _agg_leaf_kind(path)
         if name == "min":
-            return lax.pmin(jnp.min(leaf, axis=0), split_ax)
+            return _all_reduce_extremum(jnp.min(leaf, axis=0), split_ax,
+                                        "min")
         if name in ("max", "hll"):  # HLL registers merge by max too
-            return lax.pmax(jnp.max(leaf, axis=0), split_ax)
+            return _all_reduce_extremum(jnp.max(leaf, axis=0), split_ax,
+                                        "max")
         if name == "stats":
             # state vector [count, sum, sum_sq, min, max]: first three add
             return jnp.concatenate([
                 lax.psum(jnp.sum(leaf[:, :3], axis=0), split_ax),
-                lax.pmin(jnp.min(leaf[:, 3:4], axis=0), split_ax),
-                lax.pmax(jnp.max(leaf[:, 4:5], axis=0), split_ax),
+                _all_reduce_extremum(jnp.min(leaf[:, 3:4], axis=0),
+                                     split_ax, "min"),
+                _all_reduce_extremum(jnp.max(leaf[:, 4:5], axis=0),
+                                     split_ax, "max"),
             ])
         return lax.psum(jnp.sum(leaf, axis=0), split_ax)
     return jax.tree_util.tree_map_with_path(red, agg_out)
@@ -456,11 +477,11 @@ def mesh_batch_fn(batch: SplitBatch, k: int, mesh: Mesh, exact: bool = False):
     search/collector.py — runs on-mesh:
 
       1. threshold exchange: each device's k-th best primary sort value is
-         all-reduce-max'd (`pmax`) across the split axis. The max of the
-         per-device k-th values lower-bounds the global k-th value (the
-         winning device already holds k candidates at or above it), so
-         every candidate STRICTLY below it is provably outside the global
-         top-K and is masked to -inf — the cross-device analogue of
+         all-reduce-max'd (`_all_reduce_extremum`) across the split axis.
+         The max of the per-device k-th values lower-bounds the global
+         k-th value (the winning device already holds k candidates at or
+         above it), so every candidate STRICTLY below it is provably
+         outside the global top-K and is masked to -inf — the cross-device analogue of
          ops/topk.apply_threshold_mask's `>=`-keeps-ties rule, composing
          with the cross-chunk threshold the collector threads between
          dispatches.
@@ -472,7 +493,8 @@ def mesh_batch_fn(batch: SplitBatch, k: int, mesh: Mesh, exact: bool = False):
          same argument as the host `batch_fn` merge (2-key sorts ride
          `exact_topk_2key` over the gathered pairs).
       3. agg + count reduce: mergeable agg states, hit counts, and the
-         guided-top-k certificate reduce via psum/pmin/pmax.
+         guided-top-k certificate reduce via psum and
+         `_all_reduce_extremum`.
 
     The doc mesh axis shards dense column storage at rest
     (`batch_shardings`); compute replicates along it here, so collectives
@@ -480,7 +502,6 @@ def mesh_batch_fn(batch: SplitBatch, k: int, mesh: Mesh, exact: bool = False):
     results — out_specs are fully replicated. One dispatch, one packed
     scalar readback."""
     from jax import lax
-    from jax.experimental.shard_map import shard_map
 
     template = batch.template
     single_fn = executor_mod._build(template, k, exact)
@@ -496,9 +517,9 @@ def mesh_batch_fn(batch: SplitBatch, k: int, mesh: Mesh, exact: bool = False):
         sort_vals, sort_vals2, doc_ids, hit_scores, counts, topk_safe, \
             agg_out = results
         total = lax.psum(jnp.sum(counts), split_ax)
-        # one certificate for the whole batch (see batch_fn): pmin is the
+        # one certificate for the whole batch (see batch_fn): the
         # cross-device jnp.min
-        safe = lax.pmin(jnp.min(topk_safe), split_ax)
+        safe = _all_reduce_extremum(jnp.min(topk_safe), split_ax, "min")
         merged = _merge_agg_collective(agg_out, split_ax)
         if k == 0:  # count/agg-only: no candidates to exchange or gather
             empty_i = jnp.zeros((0,), jnp.int32)
@@ -506,9 +527,9 @@ def mesh_batch_fn(batch: SplitBatch, k: int, mesh: Mesh, exact: bool = False):
                     jnp.zeros((0,), hit_scores.dtype), total, safe, merged)
         flat = sort_vals.reshape(-1)          # [local_n * k], split-major
         neg_inf = jnp.asarray(-jnp.inf, flat.dtype)
-        # -- threshold exchange (one pmax round per dispatch) ------------
+        # -- threshold exchange (one all-reduce-max round per dispatch) --
         local_kth = lax.top_k(flat, k)[0][k - 1]
-        threshold = lax.pmax(local_kth, split_ax)
+        threshold = _all_reduce_extremum(local_kth, split_ax, "max")
         keep = flat >= threshold              # >= keeps threshold ties
         flat = jnp.where(keep, flat, neg_inf)
         # -- split-axis gather + re-top-k --------------------------------
@@ -532,9 +553,9 @@ def mesh_batch_fn(batch: SplitBatch, k: int, mesh: Mesh, exact: bool = False):
 
     in_arrays = tuple(P(split_ax) for _ in batch.arrays)
     in_scalars = tuple(P(split_ax) for _ in batch.scalars)
-    return shard_map(shard_body, mesh=mesh,
-                     in_specs=(in_arrays, in_scalars, P(split_ax)),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(shard_body, mesh=mesh,
+                         in_specs=(in_arrays, in_scalars, P(split_ax)),
+                         out_specs=P(), check_vma=False)
 
 
 def batch_cache_key(batch: SplitBatch, k: int, mesh: Optional[Mesh],
@@ -570,9 +591,10 @@ def abstract_mesh_batch_program(batch: SplitBatch, k: int, mesh: Mesh,
     """ClosedJaxpr of the collective whole-query program (`mesh_batch_fn`,
     minus the packed f64 readback concat) — abstract-traced, never
     compiled or executed. Unlike `abstract_batch_program`, the collectives
-    here are EXPLICIT eqns (shard_map + psum/pmax/pmin/all_gather), which
-    is what makes qwir R4's mesh-axis rule load-bearing: every collective
-    must bind axes declared by the program's ProgramSpec."""
+    here are EXPLICIT eqns (shard_map + psum/all_gather, and pmax/pmin on
+    32-bit leaves), which is what makes qwir R4's mesh-axis rule
+    load-bearing: every collective must bind axes declared by the
+    program's ProgramSpec."""
     k = min(max(0, k), batch.num_docs_padded)
     fn = mesh_batch_fn(batch, k, mesh, exact)
     arrays = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
@@ -588,16 +610,20 @@ def abstract_mesh_batch_program(batch: SplitBatch, k: int, mesh: Mesh,
 # sort over n_splits*k lanes, O(fan-out × page size), NOT corpus-scale.
 # The corpus-scale sorts it consumes already ran under the certified
 # ops/topk.py kernels inside the vmapped per-split programs.
+_MESH_MERGE_F64 = (
+    "the on-mesh root merge: the same cross-split re-top-k as batch_fn "
+    "over the all_gather'd [n_splits*k] threshold-surviving winners, plus "
+    "the k-element threshold exchange sort — bounded by fan-out times page "
+    "size.")
+# keyed by qualname, as the jaxpr eqn source frames report it
 QWIR_CERTIFIED_F64 = {
-    "fn": (
+    "batch_fn.<locals>.fn": (
         "batch_fn's cross-split merge: lax.top_k / exact_topk_2key over "
         "the flattened [n_splits*k] per-split winners — bounded by fan-out "
         "times page size, never by corpus size."),
-    "shard_body": (
-        "mesh_batch_fn's on-mesh root merge: the same cross-split "
-        "re-top-k as batch_fn over the all_gather'd [n_splits*k] "
-        "threshold-surviving winners, plus the k-element threshold "
-        "exchange sort — bounded by fan-out times page size."),
+    "mesh_batch_fn.<locals>.shard_body": "mesh_batch_fn's " + _MESH_MERGE_F64,
+    "group_mesh_fn.<locals>.shard_body": (
+        "group_mesh_fn's, per query lane, " + _MESH_MERGE_F64),
 }
 
 
@@ -614,17 +640,37 @@ def _donate_batch_inputs(mesh: Optional[Mesh] = None) -> bool:
     return mesh is None and jax.default_backend() != "cpu"
 
 
-def _collective_payload_bytes(shaped, k: int, n_splits: int) -> int:
+def _collective_payload_bytes(shaped, k: int, n_splits: int,
+                              axis_splits: int) -> int:
     """Logical bytes the mesh program's collectives carry per dispatch
     (`qw_mesh_collective_bytes_total` semantics): all_gather candidates +
-    the reduced agg/count/certificate leaves + the 8-byte threshold
-    exchange. `shaped` is the eval_shape output tree of `mesh_batch_fn`."""
-    has2 = shaped[1] is not None
-    gather = 0 if k == 0 else n_splits * k * (8 + (8 if has2 else 0) + 4 + 4)
-    reduced = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
-                  for leaf in jax.tree_util.tree_leaves(shaped[-1]))
-    reduced += 4 + 8                        # total count + safe certificate
-    exchange = 0 if k == 0 else 8           # one pmax'd f64 scalar
+    the reduced agg/count/certificate leaves + the threshold exchange.
+    `shaped` is the eval_shape output tree of `mesh_batch_fn`. A 64-bit
+    max/min travels as an all_gather over the `axis_splits` devices of the
+    split axis (`_all_reduce_extremum`), so it counts once per device;
+    sums and narrower max/mins count once."""
+    def extremum(n_elems: int, dtype) -> int:
+        itemsize = np.dtype(dtype).itemsize
+        return n_elems * itemsize * (axis_splits if itemsize >= 8 else 1)
+
+    def leaf_bytes(path, leaf) -> int:
+        n_elems = int(np.prod(leaf.shape))
+        kind = _agg_leaf_kind(path)
+        if kind in ("min", "max", "hll"):
+            return extremum(n_elems, leaf.dtype)
+        if kind == "stats":     # [count, sum, sum_sq] add; [min, max] gather
+            return (n_elems // 5 * 3 * leaf.dtype.itemsize
+                    + extremum(n_elems // 5 * 2, leaf.dtype))
+        return n_elems * leaf.dtype.itemsize
+
+    vals, vals2, _split_idx, _ids, _scores, total, safe, merged = shaped
+    gather = 0 if k == 0 else \
+        n_splits * k * (8 + (8 if vals2 is not None else 0) + 4 + 4)
+    reduced = sum(jax.tree_util.tree_leaves(
+        jax.tree_util.tree_map_with_path(leaf_bytes, merged)))
+    # total count (psum) + safe certificate (f64 min)
+    reduced += total.dtype.itemsize + extremum(1, safe.dtype)
+    exchange = 0 if k == 0 else extremum(1, vals.dtype)
     return gather + reduced + exchange
 
 
@@ -649,7 +695,8 @@ def _batch_executor(batch: SplitBatch, k: int, mesh: Optional[Mesh],
     spec = [(leaf.shape, leaf.dtype)
             for leaf in jax.tree_util.tree_leaves(shaped)]
     meta = {"collective_bytes": _collective_payload_bytes(
-        shaped, k, batch.n_splits)} if collective else None
+        shaped, k, batch.n_splits, mesh.shape[_mesh_axes(mesh)[0]])} \
+        if collective else None
 
     def packed(arrays, scalars, num_docs):
         out = fn(arrays, scalars, num_docs)
@@ -1208,38 +1255,34 @@ def _merge_agg_group_collective(agg_out, split_ax: str, q: int):
     [Q, local_n, ...]; the local reduction runs as ONE query-id-segmented
     device op over the flattened [Q*local_n, ...] rows
     (ops/topk.segment_merge_by_query), then the per-leaf combiner crosses
-    the split mesh axis per lane (psum/pmin/pmax act elementwise over the
-    leading [Q] dim). Exactness: segment_sum accumulates rows in ascending
-    index order within each segment — the same left fold over local splits
-    the single-query merge performs."""
+    the split mesh axis per lane (psum/`_all_reduce_extremum` act
+    elementwise over the leading [Q] dim). Exactness: segment_sum
+    accumulates rows in ascending index order within each segment — the
+    same left fold over local splits the single-query merge performs."""
     from jax import lax
 
     from ..ops import topk as topk_ops
 
     def red(path, leaf):
-        name = None
-        for p in reversed(path):
-            if hasattr(p, "key"):
-                name = p.key
-                break
+        name = _agg_leaf_kind(path)
         local_n = leaf.shape[1]
         flat = leaf.reshape((q * local_n,) + leaf.shape[2:])
         qids = jnp.repeat(jnp.arange(q, dtype=jnp.int32), local_n)
         if name == "min":
-            return lax.pmin(topk_ops.segment_merge_by_query(
-                flat, qids, q, "min"), split_ax)
+            return _all_reduce_extremum(topk_ops.segment_merge_by_query(
+                flat, qids, q, "min"), split_ax, "min")
         if name in ("max", "hll"):  # HLL registers merge by max too
-            return lax.pmax(topk_ops.segment_merge_by_query(
-                flat, qids, q, "max"), split_ax)
+            return _all_reduce_extremum(topk_ops.segment_merge_by_query(
+                flat, qids, q, "max"), split_ax, "max")
         if name == "stats":
             # state vector [count, sum, sum_sq, min, max]: first three add
             return jnp.concatenate([
                 lax.psum(topk_ops.segment_merge_by_query(
                     flat[:, :3], qids, q, "sum"), split_ax),
-                lax.pmin(topk_ops.segment_merge_by_query(
-                    flat[:, 3:4], qids, q, "min"), split_ax),
-                lax.pmax(topk_ops.segment_merge_by_query(
-                    flat[:, 4:5], qids, q, "max"), split_ax),
+                _all_reduce_extremum(topk_ops.segment_merge_by_query(
+                    flat[:, 3:4], qids, q, "min"), split_ax, "min"),
+                _all_reduce_extremum(topk_ops.segment_merge_by_query(
+                    flat[:, 4:5], qids, q, "max"), split_ax, "max"),
             ], axis=1)
         # segment_sum keeps the operand dtype, but the solo merge's
         # jnp.sum promotes integer accumulators (int32 counts → int64) —
@@ -1279,15 +1322,15 @@ def group_mesh_fn(batches: list, k: int, mesh: Mesh, exact: bool = False):
     elementwise over the leading [Q] dim, so lane q's merge consumes
     exactly the operands its solo program would):
 
-      1. threshold exchange: [Q] per-lane k-th values, ONE pmax round.
+      1. threshold exchange: [Q] per-lane k-th values, ONE
+         all-reduce-max round.
       2. top-K merge: [Q, local_n*k] candidates all_gather along the
          split axis (axis=1, tiled — split-major per lane), then a
          batched top-k; 2-key sorts ride `ops/topk.batched_topk_2key`.
       3. agg + count reduce: query-id-segmented local merges, then
-         per-lane psum/pmin/pmax (`_merge_agg_group_collective`).
+         per-lane psum/max/min (`_merge_agg_group_collective`).
     """
     from jax import lax
-    from jax.experimental.shard_map import shard_map
 
     template = batches[0].template
     q = len(batches)
@@ -1311,7 +1354,8 @@ def group_mesh_fn(batches: list, k: int, mesh: Mesh, exact: bool = False):
         sort_vals, sort_vals2, doc_ids, hit_scores, counts, topk_safe, \
             agg_out = results
         total = lax.psum(jnp.sum(counts, axis=1), split_ax)        # [Q]
-        safe = lax.pmin(jnp.min(topk_safe, axis=1), split_ax)      # [Q]
+        safe = _all_reduce_extremum(jnp.min(topk_safe, axis=1), split_ax,
+                                    "min")                         # [Q]
         merged = _merge_agg_group_collective(agg_out, split_ax, q)
         if k == 0:  # count/agg-only: no candidates to exchange or gather
             empty_i = jnp.zeros((q, 0), jnp.int32)
@@ -1320,9 +1364,9 @@ def group_mesh_fn(batches: list, k: int, mesh: Mesh, exact: bool = False):
                     safe, merged)
         flat = sort_vals.reshape(q, -1)     # [Q, local_n*k], split-major
         neg_inf = jnp.asarray(-jnp.inf, flat.dtype)
-        # -- threshold exchange: ONE pmax round carries all Q lanes ------
+        # -- threshold exchange: ONE round carries all Q lanes -----------
         local_kth = lax.top_k(flat, k)[0][:, k - 1]
-        threshold = lax.pmax(local_kth, split_ax)                  # [Q]
+        threshold = _all_reduce_extremum(local_kth, split_ax, "max")  # [Q]
         keep = flat >= threshold[:, None]   # >= keeps threshold ties
         flat = jnp.where(keep, flat, neg_inf)
         # -- split-axis gather + per-lane re-top-k -----------------------
@@ -1350,10 +1394,10 @@ def group_mesh_fn(batches: list, k: int, mesh: Mesh, exact: bool = False):
     in_shared = tuple(P(split_ax) for _ in shared_slots)
     in_stacked = tuple(P(None, split_ax) for _ in stacked_slots)
     in_scalars = tuple(P(None, split_ax) for _ in template.scalars)
-    return shard_map(shard_body, mesh=mesh,
-                     in_specs=(in_shared, in_stacked, in_scalars,
-                               P(split_ax)),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(shard_body, mesh=mesh,
+                         in_specs=(in_shared, in_stacked, in_scalars,
+                                   P(split_ax)),
+                         out_specs=P(), check_vma=False)
 
 
 def group_cache_key(batches: list, k: int, mesh: Optional[Mesh] = None,
@@ -1537,7 +1581,7 @@ def dispatch_query_group(batches: list, request: SearchRequest,
             MESH_DISPATCHES_TOTAL.inc()
             MESH_DEVICES.set(mesh.size)
             if k > 0:
-                # one pmax round still carries ALL Q lanes' thresholds
+                # one round still carries ALL Q lanes' thresholds
                 MESH_THRESHOLD_EXCHANGE_ROUNDS_TOTAL.inc()
             if flight.recording():
                 flight.emit("mesh.collective",

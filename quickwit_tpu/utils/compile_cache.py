@@ -1,41 +1,37 @@
 """Persistent XLA compilation cache.
 
-The flagship leaf-search program costs ~48s to compile on a TPU backend
-(BENCH_r02 warmup) — paying that once per *process* makes first-query
-latency a minute. JAX's persistent compilation cache keys executables by
-HLO fingerprint, so every process after the first loads the compiled
-binary in milliseconds. The reference has no analogue (tantivy is
-interpreted); this is TPU-build-specific operability.
+Leaf-search programs take seconds to minutes to compile for a TPU; paying
+that once per *process* puts it on every node's first queries. JAX's
+persistent compilation cache keys executables by HLO fingerprint, so every
+process after the first loads the compiled binary instead. The reference
+has no analogue (tantivy is interpreted); this is TPU-build-specific
+operability.
 
-Enabled by default for servers and benches; set QW_COMPILE_CACHE=0 to
-disable or QW_COMPILE_CACHE_DIR to relocate.
+The directory is `JAX_COMPILATION_CACHE_DIR` where that is set (JAX reads
+it itself; no directory is set in code), else `<checkout>/.jax_cache`. The
+path is fixed because it is part of what a cache hit depends on: a
+directory that moves between runs never hits.
 """
 
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
-_ENABLED = False
+_CHECKOUT_CACHE_DIR = str(Path(__file__).resolve().parents[2] / ".jax_cache")
 
 
-def enable_persistent_compile_cache(path: "str | None" = None) -> "str | None":
-    """Idempotently point JAX's compilation cache at a durable directory.
-    Returns the cache dir, or None when disabled/unsupported."""
-    global _ENABLED
-    if os.environ.get("QW_COMPILE_CACHE", "1") in ("0", "false"):
-        return None
-    cache_dir = (path or os.environ.get("QW_COMPILE_CACHE_DIR")
-                 or os.path.expanduser("~/.cache/quickwit_tpu/xla"))
-    if _ENABLED:
-        return cache_dir
-    try:
-        import jax
+def enable_persistent_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on and return its directory.
+    A directory that cannot be created raises."""
+    import jax
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = _CHECKOUT_CACHE_DIR
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache even fast compiles: the steady state is many small
-        # per-signature executables
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-        _ENABLED = True
-        return cache_dir
-    except Exception:  # noqa: BLE001 - cache is an optimization, never fatal
-        return None
+    # cache every compile, however fast: the steady state is many small
+    # per-signature executables, and a threshold would make what a restart
+    # finds depend on how long each compile happened to take
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir

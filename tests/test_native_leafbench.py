@@ -3,7 +3,7 @@
 The benchmark's honesty rests on the native denominator computing the
 SAME answer as the device path; the bench drops the denominator on a
 count mismatch, so these tests prove the agreement holds — including the
-boolean AND/OR + timestamp-range shape (c2) added for VERDICT missing #2.
+boolean AND/OR + timestamp-range shape (c2).
 """
 
 import pytest

@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 from pathlib import Path
 
@@ -20,17 +19,9 @@ def _setup_platform() -> None:
     # Mirror tests/conftest.py: force the CPU backend with 8 virtual
     # devices BEFORE jax initializes, so fused-mesh programs trace the
     # same way under the auditor as under the tier-1 suite.
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
     import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_num_cpu_devices", 8)
-    except Exception:
-        pass  # older jax: env vars above already took effect
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", 8)
 
 
 def _cmd_audit(args) -> int:

@@ -124,8 +124,21 @@ class ProgramSpec:
 
     @property
     def cache_key_digest(self) -> str:
-        return hashlib.blake2b(repr(self.cache_key).encode(),
+        return hashlib.blake2b(repr(_printable_key(self.cache_key)).encode(),
                                digest_size=16).hexdigest()
+
+
+def _printable_key(key):
+    """The cache key with every `Mesh` replaced by what identifies it
+    (axis names, device grid shape, device ids): how JAX prints a Mesh
+    changes between versions, and the certificate must not."""
+    from jax.sharding import Mesh
+    if isinstance(key, Mesh):
+        return ("Mesh", key.axis_names, key.devices.shape,
+                tuple(int(d.id) for d in key.devices.flat))
+    if isinstance(key, tuple):
+        return tuple(_printable_key(part) for part in key)
+    return key
 
 
 def _queries():
@@ -323,9 +336,9 @@ def build_corpus() -> list[ProgramSpec]:
                0, ("v3", "v3b"))
 
     # -- collective mesh root-merge programs (parallel/fanout.py) --------
-    # the whole-query shard_map programs: per-shard scoring, the pmax
-    # threshold exchange, the all_gather + re-top-k merge, and the
-    # psum/pmin/pmax agg reduction are EXPLICIT collective eqns here —
+    # the whole-query shard_map programs: per-shard scoring, the
+    # all-reduce-max threshold exchange, the all_gather + re-top-k merge,
+    # and the psum/max/min agg reduction are EXPLICIT collective eqns here —
     # R4's mesh-axis rule audits every one against the declared
     # ("splits", "docs") axes
     def mesh_spec(name, request, k, split_keys, mesh):
@@ -360,7 +373,7 @@ def build_corpus() -> list[ProgramSpec]:
     # -- stacked query-group mesh program (query axis x splits x docs) ---
     # Q distinct queries over the SAME split set fused into one shard_map
     # dispatch: the query axis is vmapped inside every device shard, and
-    # the pmax threshold exchange / all_gather merge / segment agg
+    # the threshold exchange / all_gather merge / segment agg
     # reduction run per query lane — R4 audits the collectives against the
     # same ("splits", "docs") axes as the single-query mesh programs.
     # Range windows over the timestamp zonemap are shape-compatible by

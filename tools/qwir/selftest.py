@@ -93,7 +93,6 @@ def planted_bad_collective() -> ToySpec:
     "docs" — silently wrong replica groups on a real 2D mesh."""
     import jax
     import numpy as np_
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     devs = np_.asarray(jax.devices()[:2]).reshape(1, 2)
@@ -102,8 +101,8 @@ def planted_bad_collective() -> ToySpec:
     def merge(x):
         return jax.lax.psum(x, "docs")
 
-    fn = shard_map(merge, mesh=mesh, in_specs=P(None, "docs"),
-                   out_specs=P(None, None))
+    fn = jax.shard_map(merge, mesh=mesh, in_specs=P(None, "docs"),
+                       out_specs=P(None, None), check_vma=False)
     return ToySpec(name="planted/bad_collective",
                    closed=_trace(fn, ((4, 2), np.float32)),
                    mesh_axes=("splits",))
@@ -113,7 +112,7 @@ def planted_mesh_axis_leak() -> ToySpec:
     """An undeclared-axis psum through the PRODUCTION collective program
     shape: `fanout.mesh_batch_fn` traced over a mesh whose split axis is
     misnamed ("rows", "docs"). Every collective in the lowered root merge
-    — the pmax threshold exchange, the all_gather candidate exchange, the
+    — the threshold exchange, the all_gather candidate exchange, the
     psum agg/count reductions — then binds "rows", which the ProgramSpec
     never declared. Catching this through the real builder (not a toy
     body) is what keeps R4 load-bearing for the mesh root-merge programs
