@@ -213,17 +213,14 @@ SEARCH_FETCH_DOCS_RETRIES_TOTAL = METRICS.counter(
 # --- query batcher (search/batcher.py) ------------------------------------
 # Batching efficiency is queries/dispatches: 1.0 means no coalescing,
 # higher means concurrent same-shape queries rode shared vmapped
-# dispatches. Exported as two counters (PromQL rate-ratio friendly) plus
-# a convenience gauge of the cumulative ratio.
+# dispatches. Exported as two counters (PromQL rate-ratio friendly); the
+# ratio is theirs to divide.
 SEARCH_BATCHER_QUERIES_TOTAL = METRICS.counter(
     "qw_search_batcher_queries_total",
     "Queries entering the cross-query dispatch batcher")
 SEARCH_BATCHER_DISPATCHES_TOTAL = METRICS.counter(
     "qw_search_batcher_dispatches_total",
     "Device dispatch rounds issued by the batcher")
-SEARCH_BATCHER_RATIO = METRICS.gauge(
-    "qw_search_batcher_ratio",
-    "Cumulative queries-per-dispatch coalescing ratio of the batcher")
 # Time a rider spends queued between enqueue and its dispatch starting —
 # the convoy window. Followers pay this to ride a shared dispatch.
 SEARCH_BATCHER_QUEUE_WAIT = METRICS.histogram(
@@ -426,7 +423,9 @@ PREDICATE_STAGED_BYTES_TOTAL = METRICS.counter(
     "consumer)")
 SEARCH_KERNEL_LAUNCHES_TOTAL = METRICS.counter(
     "qw_search_kernel_launches_total",
-    "Device kernel dispatches (single, multi-query, and mask-fill)")
+    "Device program launches of the served path: solo, multi-query, stacked, "
+    "mask-fill (search/executor.py) and the fused batch and query-group "
+    "families (parallel/fanout.py)")
 
 # --- chaos / fault injection (common/faults.py) ----------------------------
 # Every fault the injector actually fired, labeled op=<operation>

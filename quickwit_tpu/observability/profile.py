@@ -16,11 +16,19 @@ with `bind_profile`. When no profile is bound — the default — every hook is
 one ContextVar get returning None: no phase objects are allocated on the
 hot path.
 
-Each recorded phase also opens a span on the process tracer
-(`observability/tracing.py`) so the same waterfall stitches into OTLP
-traces, and phase durations feed the `qw_search_phase_seconds` histogram
-(labeled by phase) so fleet-wide attribution is queryable without
+Each phase timed with the `with` form also lands on two other clocks, at
+no cost when nobody reads them: a `phase.<name>` span on the process tracer
+(`observability/tracing.py`) while a span processor is registered, so the
+waterfall stitches into OTLP traces; and a `qw.<name>` event in the
+`jax.profiler` trace (`TraceAnnotation`, carrying `query_id` and `stage`)
+while a profiler session runs, so host phases sit on the same clock as the
+device's operations. Phase durations feed the `qw_search_phase_seconds`
+histogram (labeled by phase) so fleet-wide attribution is queryable without
 capturing any single profile.
+
+This module also lists the scope vocabulary (`SCOPE_*`): the fixed names
+`jax.named_scope` puts on the stages of the jitted leaf programs, so device
+time in a profiler trace groups by plan stage.
 """
 
 from __future__ import annotations
@@ -57,6 +65,42 @@ PHASE_TOPK_MERGE = "topk_merge"
 PHASE_ROOT_MERGE = "root_merge"
 PHASE_FETCH_DOCS = "fetch_docs"
 PHASE_LEAF_SEARCH = "leaf_search"
+# host work between a dispatch decision and the program's launch: scalar
+# upload, operand stacking, lane padding (executor.dispatch_plan*)
+PHASE_DISPATCH_PREPARE = "dispatch_prepare"
+# launch + blocking readback of the mask-tier fill program
+# (executor.compute_packed_mask), on the request that pays for it
+PHASE_MASK_FILL = "mask_fill"
+# a rider of a shared dispatch other than its leader: from the leader's
+# dispatch (where its queue-wait phase ends) to the rider's result
+PHASE_GROUP_EXECUTE_WAIT = "group_execute_wait"
+# root, before the fan-out: metastore lookups, split listing and pruning,
+# job placement
+PHASE_ROOT_PLAN = "root_plan"
+# root, after fetch_docs: aggregation finalisation and the response
+PHASE_ROOT_FINALIZE = "root_finalize"
+# leaf, before the first split is prepared: doc mapper, split order,
+# leaf-cache / predicate-cache / agg-tier lookups
+PHASE_LEAF_PREPARE = "leaf_prepare"
+# opening a split's reader (footer + hotcache IO on a cold reader)
+PHASE_SPLIT_OPEN = "split_open"
+# mask- and agg-tier lookups for one split, before lowering
+PHASE_CACHE_LOOKUP = "cache_lookup"
+# mask-/agg-tier and leaf-cache puts after a split executed
+PHASE_CACHE_FILL = "cache_fill"
+
+# Scope vocabulary: the `jax.named_scope` names on the stages of the jitted
+# leaf programs (search/executor.py, ops/*.py). Metadata only — a scope adds
+# no operation. A device operation counts under the OUTERMOST of these
+# names in its framework-op path, once.
+SCOPE_TERM_MASK = "term_mask"       # postings -> doc mask
+SCOPE_BM25_SCORE = "bm25_score"     # postings -> dense BM25 scores
+SCOPE_RANGE_FILTER = "range_filter"
+SCOPE_SORT_KEY = "sort_key"         # column gathers and sort keying
+SCOPE_TOPK = "topk"
+SCOPE_AGGS = "aggs"                 # aggs.<kind>: terms, range, percentiles…
+SCOPE_PACK = "pack"                 # result tree -> one f64 readback buffer
+SCOPE_MASK_FILL = "mask_fill"       # the whole mask-tier fill program
 
 
 class QueryProfile:
@@ -92,16 +136,22 @@ class QueryProfile:
     # --- recording ---------------------------------------------------------
     @contextmanager
     def phase(self, name: str, **attrs: Any):
-        """Time one phase; opens a `phase.<name>` span on the tracer so the
-        waterfall stitches into OTLP. Yields the mutable record so callers
-        can attach result attributes (bytes, cache hit, threshold, ...)."""
+        """Time one phase: the calling thread is doing or awaiting that work
+        for the whole block. Also a `phase.<name>` span on the tracer while
+        a span processor is registered (the waterfall stitches into OTLP)
+        and a `qw.<name>` event in a running `jax.profiler` trace. Yields
+        the mutable record so callers can attach result attributes (bytes,
+        cache hit, threshold, ...)."""
         from .tracing import TRACER
         start = time.monotonic()
         record: dict[str, Any] = dict(attrs)
         record["name"] = name
         record["start_ms"] = round((start - self.created_at) * 1000.0, 3)
         try:
-            with TRACER.span(f"phase.{name}"):
+            with _trace_annotation()(f"qw.{name}", query_id=self.query_id,
+                                     stage=str(attrs.get("stage", ""))), \
+                    (TRACER.span(f"phase.{name}") if TRACER.has_processors
+                     else _NULL_PHASE):
                 yield record
         except BaseException:
             record["aborted"] = True
@@ -203,6 +253,20 @@ class QueryProfile:
         if children:
             out["leaves"] = children
         return out
+
+
+_TRACE_ANNOTATION = None
+
+
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation`, imported once and late: `storage/`
+    and `tenancy/` import this module and must not pull JAX in. Inert when
+    no profiler session runs."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION
 
 
 # --- ambient propagation (mirrors common/deadline.py) ----------------------

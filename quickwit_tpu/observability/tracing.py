@@ -91,6 +91,12 @@ class Tracer:
         if processor in self._processors:
             self._processors.remove(processor)
 
+    @property
+    def has_processors(self) -> bool:
+        """Whether a finished span would reach anybody. Call sites that
+        open spans purely for export (profile phases) skip them otherwise."""
+        return bool(self._processors)
+
     # --- context -----------------------------------------------------------
     def _stack(self) -> list[SpanData]:
         stack = getattr(self._tls, "stack", None)

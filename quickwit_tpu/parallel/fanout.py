@@ -41,10 +41,11 @@ from ..index.format import ZONEMAP_BLOCK
 from ..index.reader import SplitReader
 from ..models.doc_mapper import DocMapper
 from ..observability import flight
+from ..observability.metrics import SEARCH_KERNEL_LAUNCHES_TOTAL
 from ..observability.profile import (
     PHASE_COMPILE, PHASE_EXECUTE, PHASE_PLAN_BUILD, PHASE_STAGING_CACHE_HIT,
-    PHASE_STAGING_UPLOAD, PHASE_TOPK_MERGE, current_profile, profile_add,
-    profiled_phase,
+    PHASE_STAGING_UPLOAD, PHASE_TOPK_MERGE, SCOPE_PACK, SCOPE_TOPK,
+    current_profile, profile_add, profiled_phase,
 )
 from ..query.aggregations import DateHistogramAgg, HistogramAgg, TermsAgg, parse_aggs
 from ..search.models import LeafSearchResponse, PartialHit, SearchRequest
@@ -381,18 +382,20 @@ def batch_fn(batch: SplitBatch, k: int, exact: bool = False):
                     _merge_agg_stack(agg_out))
         # flatten [n, k] → [n*k]; split-major order keeps the
         # (key desc, split asc, doc asc) tie-break of the collector
-        if sort_vals2 is None:
-            top_vals, pos = jax.lax.top_k(sort_vals.reshape(-1), k)
-            top_vals2 = None
-        else:
-            # 2-key sorts: lexicographic cross-split re-top-k (the same
-            # kernel the per-split path uses, over the flattened winners)
-            from ..ops import topk as topk_ops
-            top_vals, top_vals2, pos = topk_ops.exact_topk_2key(
-                sort_vals.reshape(-1), sort_vals2.reshape(-1), k)
-        split_idx = (pos // k).astype(jnp.int32)
-        flat_ids = doc_ids.reshape(-1)[pos]
-        flat_scores = hit_scores.reshape(-1)[pos]
+        with jax.named_scope(SCOPE_TOPK):   # the cross-split merge
+            if sort_vals2 is None:
+                top_vals, pos = jax.lax.top_k(sort_vals.reshape(-1), k)
+                top_vals2 = None
+            else:
+                # 2-key sorts: lexicographic cross-split re-top-k (the
+                # same kernel the per-split path uses, over the flattened
+                # winners)
+                from ..ops import topk as topk_ops
+                top_vals, top_vals2, pos = topk_ops.exact_topk_2key(
+                    sort_vals.reshape(-1), sort_vals2.reshape(-1), k)
+            split_idx = (pos // k).astype(jnp.int32)
+            flat_ids = doc_ids.reshape(-1)[pos]
+            flat_scores = hit_scores.reshape(-1)[pos]
         return top_vals, top_vals2, split_idx, flat_ids, flat_scores, \
             total, safe, _merge_agg_stack(agg_out)
 
@@ -700,10 +703,14 @@ def _batch_executor(batch: SplitBatch, k: int, mesh: Optional[Mesh],
 
     def packed(arrays, scalars, num_docs):
         out = fn(arrays, scalars, num_docs)
-        flat = [leaf.reshape(-1).astype(jnp.float64)
-                for leaf in jax.tree_util.tree_leaves(out)]
-        return jnp.concatenate(flat) if flat else jnp.zeros((0,))
+        with jax.named_scope(SCOPE_PACK):
+            flat = [leaf.reshape(-1).astype(jnp.float64)
+                    for leaf in jax.tree_util.tree_leaves(out)]
+            return jnp.concatenate(flat) if flat else jnp.zeros((0,))
 
+    # static program name from the cache key alone: family, lanes, k, mesh
+    executor_mod._named(packed, f"qw_batch_s{batch.n_splits}_k{k}" + (
+        f"_mesh{mesh.size}" if collective else ""))
     donate = (0,) if _donate_batch_inputs(mesh) else ()
     if mesh is None:
         return jax.jit(packed, donate_argnums=donate), treedef, spec, meta
@@ -929,7 +936,9 @@ def _enqueue_batch(ex, arrays, scalars, nd, mesh):
     """Enqueue one batch program; returns (out, guard). `guard` is the
     still-held `_MESH_DISPATCH_LOCK` on the CPU host platform (the caller
     MUST hand it to `_finish_mesh_dispatch` once the program has been
-    awaited), None otherwise."""
+    awaited), None otherwise. Every launch of the fused batch and
+    query-group families passes here, so here it is counted."""
+    SEARCH_KERNEL_LAUNCHES_TOTAL.inc()
     if mesh is None:
         return ex(arrays, scalars, nd), None
     _MESH_DISPATCH_LOCK.acquire()
@@ -1467,12 +1476,16 @@ def _group_executor(batches: list, k: int, mesh: Optional[Mesh],
 
     def packed(shared, stacked, scalars_b, num_docs, valid):
         out = fn(shared, stacked, scalars_b, num_docs)
-        flat = [leaf.reshape(q, -1).astype(jnp.float64)
-                for leaf in jax.tree_util.tree_leaves(out)]
-        packed_rows = jnp.concatenate(flat, axis=1) if flat \
-            else jnp.zeros((q, 0))
-        return jnp.where(valid[:, None], packed_rows, 0.0)
+        with jax.named_scope(SCOPE_PACK):
+            flat = [leaf.reshape(q, -1).astype(jnp.float64)
+                    for leaf in jax.tree_util.tree_leaves(out)]
+            packed_rows = jnp.concatenate(flat, axis=1) if flat \
+                else jnp.zeros((q, 0))
+            return jnp.where(valid[:, None], packed_rows, 0.0)
 
+    executor_mod._named(
+        packed, f"qw_group_q{q}_s{batches[0].n_splits}_k{k}" + (
+            f"_mesh{mesh.size}" if mesh is not None else ""))
     return jax.jit(packed), treedef, spec
 
 

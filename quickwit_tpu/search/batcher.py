@@ -59,10 +59,11 @@ from ..observability.metrics import (
     QBATCH_GROUPS_TOTAL, QBATCH_INCOMPATIBLE_TOTAL,
     QBATCH_MASKED_RIDERS_TOTAL, QBATCH_QUERIES_PER_DISPATCH,
     SEARCH_BATCHER_DISPATCHES_TOTAL, SEARCH_BATCHER_QUERIES_TOTAL,
-    SEARCH_BATCHER_QUEUE_WAIT, SEARCH_BATCHER_RATIO, SEARCH_SHED_TOTAL,
+    SEARCH_BATCHER_QUEUE_WAIT, SEARCH_SHED_TOTAL,
 )
 from ..observability.profile import (
-    PHASE_BATCHER_QUEUE, PHASE_QBATCH_GROUP, current_profile,
+    PHASE_BATCHER_QUEUE, PHASE_GROUP_EXECUTE_WAIT, PHASE_QBATCH_GROUP,
+    current_profile,
 )
 from ..tenancy.context import effective_tenant
 from ..tenancy.overload import OVERLOAD, OverloadShed
@@ -123,7 +124,7 @@ class _PriorityLock:
 class _Pending:
     __slots__ = ("plan", "arrays", "scalars", "tbox", "tenant", "event",
                  "result", "error", "deadline", "enqueued_at", "profile",
-                 "cancel")
+                 "cancel", "ride")
 
     def __init__(self, scalars, deadline: Optional[Deadline] = None,
                  profile=None, cancel: Optional[CancellationToken] = None,
@@ -148,6 +149,21 @@ class _Pending:
         # both the rider's own wait and the leader's shed points, so a
         # cancelled rider neither blocks on nor is served by the batch
         self.cancel = cancel
+        # (dispatched_at, riders, lane) once a leader has dispatched for
+        # this rider: where its queue-wait phase ended
+        self.ride: Optional[tuple] = None
+
+    def serve(self) -> None:
+        """Wake the rider. One served by another thread's dispatch has
+        waited through that group's device run and readback, which are
+        phases of the leader's profile only: record the wait on the
+        rider's own, from the dispatch to now, before it wakes."""
+        if self.ride is not None and self.profile is not None:
+            (dispatched_at, riders, lane), self.ride = self.ride, None
+            self.profile.record_phase(
+                PHASE_GROUP_EXECUTE_WAIT, time.monotonic() - dispatched_at,
+                start=dispatched_at, riders=riders, lane=lane)
+        self.event.set()
 
 
 class QueryGroupPlanner:
@@ -383,11 +399,12 @@ class QueryBatcher:
                                     phase, wait,
                                     start=pending.enqueued_at,
                                     riders=len(alive))
+                                if pending is not me:
+                                    pending.ride = (now, len(alive),
+                                                    batch.index(pending))
                         with self._lock:
                             self.num_dispatches += 1
                             SEARCH_BATCHER_DISPATCHES_TOTAL.inc()
-                            SEARCH_BATCHER_RATIO.set(
-                                self.num_queries / self.num_dispatches)
                         if self.fault_injector is not None:
                             self.fault_injector.perturb("batcher.dispatch")
                         if len(batch) == 1 and alive[0] is me:
@@ -425,7 +442,7 @@ class QueryBatcher:
                 except Exception as exc:  # noqa: BLE001 - fan to waiters
                     for pending in alive:
                         pending.error = exc
-                        pending.event.set()
+                        pending.serve()
             finally:
                 # released after DISPATCH, before the blocking readback:
                 # the next convoy for this key overlaps its dispatch with
@@ -453,7 +470,7 @@ class QueryBatcher:
                             else:
                                 pending.error = DeadlineExceeded(
                                     "batched readback shed")
-                            pending.event.set()
+                            pending.serve()
                     else:
                         results = readback_fn()
                         for pending, result in zip(readback_targets,
@@ -476,13 +493,13 @@ class QueryBatcher:
                                 pending.error = result
                             else:
                                 pending.result = result
-                            pending.event.set()
+                            pending.serve()
                 # qwlint: disable-next-line=QW004 - fanned to waiters and
                 # re-raised per-waiter, same contract as the dispatch side
                 except Exception as exc:  # noqa: BLE001 - fan to waiters
                     for pending in alive:
                         pending.error = exc
-                        pending.event.set()
+                        pending.serve()
         finally:
             with self._lock:
                 entry = self._dispatch_locks.get(key)

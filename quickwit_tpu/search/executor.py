@@ -28,7 +28,10 @@ import numpy as np
 from ..common.clock import monotonic as _clock_monotonic
 from ..index.format import ZONEMAP_BLOCK
 from ..observability.profile import (
-    PHASE_COMPILE, PHASE_EXECUTE, current_profile,
+    PHASE_COMPILE, PHASE_DISPATCH_PREPARE, PHASE_EXECUTE, PHASE_MASK_FILL,
+    SCOPE_AGGS, SCOPE_BM25_SCORE, SCOPE_MASK_FILL, SCOPE_PACK,
+    SCOPE_RANGE_FILTER, SCOPE_SORT_KEY, SCOPE_TERM_MASK, SCOPE_TOPK,
+    current_profile, profile_add, profiled_phase,
 )
 from ..ops import aggs as agg_ops
 from ..ops import masks as mask_ops
@@ -50,6 +53,16 @@ _JIT_CACHE: dict[tuple, Callable] = {}
 # re-used every query must not be evicted just because it was inserted first.
 _SCALAR_CACHE: "OrderedDict[tuple, Any]" = OrderedDict()
 _SCALAR_CACHE_CAP = 512
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """Give the closure about to be jitted a static, readable name: XLA
+    calls the program `jit_<name>`, which is what a profiler trace and the
+    compile log show. `name` derives only from what is already in the
+    program's cache key (family, lane bucket, k) — never from a request's
+    scalars, terms or posting lengths, so it adds no compiled program."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 # qwlint: disable-next-line=QW001 - .item() on host numpy scalars builds
@@ -446,15 +459,18 @@ def _build_posting_space(plan: LoweredPlan, k: int,
             return sort_vals, None, doc_ids.astype(jnp.int32), hit_scores, \
                 count, jnp.float64(1.0), tuple(agg_out)
         if root.scoring:
-            scores = score_postings(
-                tfs, ids, arrays[root.norm_slot],
-                scalars[root.avg_len_slot], scalars[root.idf_slot])
+            with jax.named_scope(SCOPE_BM25_SCORE):
+                scores = score_postings(
+                    tfs, ids, arrays[root.norm_slot],
+                    scalars[root.avg_len_slot], scalars[root.idf_slot])
         else:
             scores = jnp.zeros(num_postings, dtype=jnp.float32)
         gathered = _GatherView(arrays, safe_ids, scalars, plan.rebase)
         # "doc" sorts key on the posting's doc id (ascending already)
-        keyed = _keyed_for(sort.by, sort.descending, sort.values_slot,
-                           sort.present_slot, gathered, valid, scores, ids)
+        with jax.named_scope(SCOPE_SORT_KEY):
+            keyed = _keyed_for(sort.by, sort.descending, sort.values_slot,
+                               sort.present_slot, gathered, valid, scores,
+                               ids)
         if plan.threshold_slot >= 0:
             # dynamic pruning pushdown: counts/aggs above keep full-query
             # semantics; only top-k eligibility is restricted
@@ -475,21 +491,26 @@ def _build_posting_space(plan: LoweredPlan, k: int,
         kk = min(k, num_postings)
         topk_safe = jnp.float64(1.0)
         if sort.by2 == "none":
-            if exact:
-                sort_vals, pos = topk_ops.exact_topk(keyed, kk)
-            else:
-                sort_vals, pos, topk_safe = topk_ops.guided_topk(keyed, kk)
+            with jax.named_scope(SCOPE_TOPK):
+                if exact:
+                    sort_vals, pos = topk_ops.exact_topk(keyed, kk)
+                else:
+                    sort_vals, pos, topk_safe = topk_ops.guided_topk(keyed,
+                                                                     kk)
             sort_vals2 = None
         else:
-            keyed2 = _keyed_for(sort.by2, sort.descending2, sort.values2_slot,
-                                sort.present2_slot, gathered, valid, scores,
-                                ids)
-            if plan.threshold_slot >= 0:
-                keyed2 = mask_ops.propagate_dead_lanes(keyed, keyed2)
-            sort_vals, sort_vals2, pos = topk_ops.exact_topk_2key(
-                keyed, keyed2, kk)
-        doc_ids = ids[pos]
-        hit_scores = scores[pos]
+            with jax.named_scope(SCOPE_SORT_KEY):
+                keyed2 = _keyed_for(sort.by2, sort.descending2,
+                                    sort.values2_slot, sort.present2_slot,
+                                    gathered, valid, scores, ids)
+                if plan.threshold_slot >= 0:
+                    keyed2 = mask_ops.propagate_dead_lanes(keyed, keyed2)
+            with jax.named_scope(SCOPE_TOPK):
+                sort_vals, sort_vals2, pos = topk_ops.exact_topk_2key(
+                    keyed, keyed2, kk)
+        with jax.named_scope(SCOPE_TOPK):
+            doc_ids = ids[pos]
+            hit_scores = scores[pos]
         agg_out = _eval_aggs(aggs, gathered, scalars, valid)
         return sort_vals, sort_vals2, doc_ids.astype(jnp.int32), hit_scores, \
             count, topk_safe, tuple(agg_out)
@@ -631,29 +652,39 @@ def _eval_composite_agg(a: CompositeAggExec, arrays, scalars, mask):
     return out
 
 
+def _agg_scope(a) -> str:
+    """`aggs.<kind>`: the kind is part of the plan's structure signature."""
+    if isinstance(a, CompositeAggExec):
+        return f"{SCOPE_AGGS}.composite"
+    if isinstance(a, BucketAggExec):
+        return f"{SCOPE_AGGS}.{a.kind}"
+    if isinstance(a, MetricAggExec):
+        return f"{SCOPE_AGGS}.{a.metric.kind}"
+    raise TypeError(f"unknown agg exec {type(a).__name__}")
+
+
 def _eval_aggs(aggs, gathered, scalars, valid):
     agg_out = []
     for a in aggs:
-        if isinstance(a, CompositeAggExec):
-            agg_out.append(_eval_composite_agg(a, gathered, scalars, valid))
-        elif isinstance(a, BucketAggExec):
-            agg_out.append(_eval_bucket_agg(a, gathered, scalars, valid))
-        elif isinstance(a, MetricAggExec):
-            met = a.metric
-            if met.kind == "cardinality":
-                hashes, present = _cardinality_hashes(met, gathered)
-                agg_out.append(
-                    {"hll": agg_ops.hll_registers(hashes, valid & present)})
-                continue
-            mv = gathered[met.values_slot]
-            mp = gathered[met.present_slot]
-            if met.kind == "percentiles":
-                agg_out.append({"sketch": agg_ops.percentile_sketch(mv, mp, valid)})
-            else:
-                agg_out.append({"stats": agg_ops.stats_state(mv, mp, valid)})
-        else:
-            raise TypeError(f"unknown agg exec {type(a).__name__}")
+        with jax.named_scope(_agg_scope(a)):
+            agg_out.append(_eval_agg(a, gathered, scalars, valid))
     return agg_out
+
+
+def _eval_agg(a, gathered, scalars, valid):
+    if isinstance(a, CompositeAggExec):
+        return _eval_composite_agg(a, gathered, scalars, valid)
+    if isinstance(a, BucketAggExec):
+        return _eval_bucket_agg(a, gathered, scalars, valid)
+    met = a.metric
+    if met.kind == "cardinality":
+        hashes, present = _cardinality_hashes(met, gathered)
+        return {"hll": agg_ops.hll_registers(hashes, valid & present)}
+    mv = gathered[met.values_slot]
+    mp = gathered[met.present_slot]
+    if met.kind == "percentiles":
+        return {"sketch": agg_ops.percentile_sketch(mv, mp, valid)}
+    return {"stats": agg_ops.stats_state(mv, mp, valid)}
 
 
 def _pack_mask(mask, padded: int):
@@ -693,32 +724,19 @@ def _node_evaluator(padded: int) -> Callable:
             return _unpack_mask(arrays[node.packed_slot], padded), None
         if isinstance(node, PPostings):
             ids = arrays[node.ids_slot]
-            mask = mask_ops.mask_from_postings(ids, padded)
+            with jax.named_scope(SCOPE_TERM_MASK):
+                mask = mask_ops.mask_from_postings(ids, padded)
             if not node.scoring:
                 return mask, None
-            partial = score_postings(
-                arrays[node.tfs_slot], ids, arrays[node.norm_slot],
-                scalars[node.avg_len_slot], scalars[node.idf_slot])
-            scores = mask_ops.dense_from_postings(ids, partial, padded)
+            with jax.named_scope(SCOPE_BM25_SCORE):
+                partial = score_postings(
+                    arrays[node.tfs_slot], ids, arrays[node.norm_slot],
+                    scalars[node.avg_len_slot], scalars[node.idf_slot])
+                scores = mask_ops.dense_from_postings(ids, partial, padded)
             return mask, scores
         if isinstance(node, PRange):
-            values = arrays[node.values_slot]
-            if values.dtype.kind == "u" and values.dtype.itemsize <= 4:
-                # FOR-packed lanes compare as scaled deltas in i32 — the
-                # lowering caps the span so span + 1 (the never-matching
-                # bound) stays representable
-                values = values.astype(jnp.int32)
-            return mask_ops.range_mask(
-                values, arrays[node.present_slot],
-                scalars[node.lo_slot] if node.lo_slot >= 0 else 0,
-                scalars[node.hi_slot] if node.hi_slot >= 0 else 0,
-                node.lo_incl, node.hi_incl,
-                node.lo_slot >= 0, node.hi_slot >= 0,
-                zmin=(arrays[node.zmin_slot]
-                      if node.zmin_slot >= 0 else None),
-                zmax=(arrays[node.zmax_slot]
-                      if node.zmax_slot >= 0 else None),
-                zonemap_block=ZONEMAP_BLOCK), None
+            with jax.named_scope(SCOPE_RANGE_FILTER):
+                return _range_node_mask(node, arrays, scalars), None
         if isinstance(node, PPresence):
             col = arrays[node.present_slot]
             return (col >= 0) if node.is_ordinal else col.astype(jnp.bool_), None
@@ -727,6 +745,25 @@ def _node_evaluator(padded: int) -> Callable:
         if isinstance(node, PBool):
             return eval_bool(node, arrays, scalars)
         raise TypeError(f"unknown plan node {type(node).__name__}")
+
+    def _range_node_mask(node: PRange, arrays, scalars):
+        values = arrays[node.values_slot]
+        if values.dtype.kind == "u" and values.dtype.itemsize <= 4:
+            # FOR-packed lanes compare as scaled deltas in i32 — the
+            # lowering caps the span so span + 1 (the never-matching
+            # bound) stays representable
+            values = values.astype(jnp.int32)
+        return mask_ops.range_mask(
+            values, arrays[node.present_slot],
+            scalars[node.lo_slot] if node.lo_slot >= 0 else 0,
+            scalars[node.hi_slot] if node.hi_slot >= 0 else 0,
+            node.lo_incl, node.hi_incl,
+            node.lo_slot >= 0, node.hi_slot >= 0,
+            zmin=(arrays[node.zmin_slot]
+                  if node.zmin_slot >= 0 else None),
+            zmax=(arrays[node.zmax_slot]
+                  if node.zmax_slot >= 0 else None),
+            zonemap_block=ZONEMAP_BLOCK)
 
     def eval_bool(node: PBool, arrays, scalars):
         score_parts = []
@@ -758,9 +795,13 @@ def _node_evaluator(padded: int) -> Callable:
             mask = mask & ~m
         scores = None
         if score_parts:
-            scores = score_parts[0]
-            for s in score_parts[1:]:
-                scores = scores + s
+            # the sum of the clauses' scores is scoring too: XLA fuses a
+            # clause's postings->score scatter into this add and names the
+            # fusion after it
+            with jax.named_scope(SCOPE_BM25_SCORE):
+                scores = score_parts[0]
+                for s in score_parts[1:]:
+                    scores = scores + s
         return mask, scores
 
     return eval_node
@@ -787,38 +828,44 @@ def _build(plan: LoweredPlan, k: int, exact: bool = False) -> Callable:
             return (jnp.zeros((0,), jnp.float64), None,
                     jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.float32),
                     count, jnp.float64(1.0), tuple(agg_out))
-        doc_key = _global_doc_ids(plan, scalars, padded)
-        keyed = _keyed_for(sort.by, sort.descending, sort.values_slot,
-                           sort.present_slot, view, mask, scores, doc_key)
-        keyed2 = None
-        if sort.by2 != "none":
-            keyed2 = _keyed_for(sort.by2, sort.descending2, sort.values2_slot,
-                                sort.present2_slot, view, mask, scores,
-                                doc_key)
-        # search_after pushdown: restrict top-k eligibility, NOT counts/aggs
-        # (ES semantics: totals and aggregations cover the full query)
-        if plan.search_after_relation != "none":
-            keyed, keyed2 = _apply_search_after(plan, keyed, keyed2, scalars,
-                                                padded)
-        if plan.threshold_slot >= 0:
-            # dynamic-pruning threshold: same eligibility-only contract
-            keyed = topk_ops.apply_threshold_mask(
-                keyed, scalars[plan.threshold_slot])
-            if keyed2 is not None:
-                keyed2 = mask_ops.propagate_dead_lanes(keyed, keyed2)
+        with jax.named_scope(SCOPE_SORT_KEY):
+            doc_key = _global_doc_ids(plan, scalars, padded)
+            keyed = _keyed_for(sort.by, sort.descending, sort.values_slot,
+                               sort.present_slot, view, mask, scores,
+                               doc_key)
+            keyed2 = None
+            if sort.by2 != "none":
+                keyed2 = _keyed_for(sort.by2, sort.descending2,
+                                    sort.values2_slot, sort.present2_slot,
+                                    view, mask, scores, doc_key)
+            # search_after pushdown: restrict top-k eligibility, NOT
+            # counts/aggs (ES semantics: totals and aggregations cover the
+            # full query)
+            if plan.search_after_relation != "none":
+                keyed, keyed2 = _apply_search_after(plan, keyed, keyed2,
+                                                    scalars, padded)
+            if plan.threshold_slot >= 0:
+                # dynamic-pruning threshold: same eligibility-only contract
+                keyed = topk_ops.apply_threshold_mask(
+                    keyed, scalars[plan.threshold_slot])
+                if keyed2 is not None:
+                    keyed2 = mask_ops.propagate_dead_lanes(keyed, keyed2)
         topk_safe = jnp.float64(1.0)
-        if keyed2 is None:
-            if exact:
-                sort_vals, doc_ids = topk_ops.exact_topk(keyed, k)
+        with jax.named_scope(SCOPE_TOPK):
+            if keyed2 is None:
+                if exact:
+                    sort_vals, doc_ids = topk_ops.exact_topk(keyed, k)
+                else:
+                    sort_vals, doc_ids, topk_safe = topk_ops.guided_topk(
+                        keyed, k)
+                sort_vals2 = None
             else:
-                sort_vals, doc_ids, topk_safe = topk_ops.guided_topk(keyed, k)
-            sort_vals2 = None
-        else:
-            sort_vals, sort_vals2, doc_ids = topk_ops.exact_topk_2key(
-                keyed, keyed2, k)
-        doc_ids = doc_ids.astype(jnp.int32)
+                sort_vals, sort_vals2, doc_ids = topk_ops.exact_topk_2key(
+                    keyed, keyed2, k)
+            doc_ids = doc_ids.astype(jnp.int32)
         count = jnp.sum(mask.astype(jnp.int32))
-        hit_scores = scores[jnp.clip(doc_ids, 0, padded - 1)]
+        with jax.named_scope(SCOPE_TOPK):   # the winners' scores
+            hit_scores = scores[jnp.clip(doc_ids, 0, padded - 1)]
         agg_out = _eval_aggs(aggs, view, scalars, mask)
         return sort_vals, sort_vals2, doc_ids, hit_scores, count, topk_safe, \
             tuple(agg_out)
@@ -830,7 +877,7 @@ def get_executor(plan: LoweredPlan, k: int, exact: bool = False) -> Callable:
     key = (plan.signature(k), exact)
     cached = _JIT_CACHE.get(key)
     if cached is None:
-        cached = jax.jit(_build(plan, k, exact))
+        cached = jax.jit(_named(_build(plan, k, exact), f"qw_plain_k{k}"))
         _JIT_CACHE[key] = cached
     return cached
 
@@ -862,11 +909,12 @@ def _get_packed_executor(plan: LoweredPlan, k: int, example_args,
 
         def packed(arrays, scalars, num_docs):
             out = fn(arrays, scalars, num_docs)
-            flat = [leaf.reshape(-1).astype(jnp.float64)
-                    for leaf in jax.tree_util.tree_leaves(out)]
-            return jnp.concatenate(flat) if flat else jnp.zeros((0,))
+            with jax.named_scope(SCOPE_PACK):
+                flat = [leaf.reshape(-1).astype(jnp.float64)
+                        for leaf in jax.tree_util.tree_leaves(out)]
+                return jnp.concatenate(flat) if flat else jnp.zeros((0,))
 
-        cached = (jax.jit(packed), treedef, spec)
+        cached = (jax.jit(_named(packed, f"qw_solo_k{k}")), treedef, spec)
         _PACKED_CACHE[key] = cached
     return cached
 
@@ -938,12 +986,14 @@ def _get_packed_multi_executor(plan: LoweredPlan, k: int, batch: int,
         def multi(arrays, scal_b, nd_b):
             out = jax.vmap(lambda s, n: fn(arrays, s, n),
                            in_axes=(0, 0))(scal_b, nd_b)
-            flat = [leaf.reshape(leaf.shape[0], -1).astype(jnp.float64)
-                    for leaf in jax.tree_util.tree_leaves(out)]
-            return (jnp.concatenate(flat, axis=1) if flat
-                    else jnp.zeros((batch, 0)))
+            with jax.named_scope(SCOPE_PACK):
+                flat = [leaf.reshape(leaf.shape[0], -1).astype(jnp.float64)
+                        for leaf in jax.tree_util.tree_leaves(out)]
+                return (jnp.concatenate(flat, axis=1) if flat
+                        else jnp.zeros((batch, 0)))
 
-        cached = (jax.jit(multi), treedef, spec)
+        cached = (jax.jit(_named(multi, f"qw_multi_b{batch}_k{k}")),
+                  treedef, spec)
         _MULTI_CACHE[key] = cached
     return cached
 
@@ -990,9 +1040,10 @@ def dispatch_plan_multi(plan: LoweredPlan, k: int,
     SEARCH_KERNEL_LAUNCHES_TOTAL.inc()
     batch = len(scalar_sets)
     bucket = _batch_bucket(batch)
-    padded_sets = list(scalar_sets) + [scalar_sets[-1]] * (bucket - batch)
-    scal_b, nd_b = _device_multi_scalars(plan, padded_sets,
-                                         use_cache=cache_scalars)
+    with profiled_phase(PHASE_DISPATCH_PREPARE):
+        padded_sets = list(scalar_sets) + [scalar_sets[-1]] * (bucket - batch)
+        scal_b, nd_b = _device_multi_scalars(plan, padded_sets,
+                                             use_cache=cache_scalars)
     profile = current_profile()
     recording = flight.recording()
     # shared once-per-dispatch cache key (see dispatch_plan)
@@ -1151,19 +1202,22 @@ def _get_packed_stacked_executor(plan: LoweredPlan, k: int, bucket: int,
             return tuple(arrays)
 
         def stacked(shared_arrays, lane_stacks, scal_b, nd_b, valid_b):
-            st = tuple(jnp.stack(qs) for qs in lane_stacks)
+            with jax.named_scope(SCOPE_PACK):   # operands onto the query axis
+                st = tuple(jnp.stack(qs) for qs in lane_stacks)
             out = jax.vmap(
                 lambda lane, s, n: fn(assemble(shared_arrays, lane), s, n),
                 in_axes=(0, 0, 0))(st, scal_b, nd_b)
-            flat = [leaf.reshape(leaf.shape[0], -1).astype(jnp.float64)
-                    for leaf in jax.tree_util.tree_leaves(out)]
-            packed = (jnp.concatenate(flat, axis=1) if flat
-                      else jnp.zeros((bucket, 0)))
-            # masked lanes zero via where, NOT multiply: sort lanes hold
-            # -inf pads and -inf * 0 is NaN
-            return jnp.where(valid_b[:, None], packed, 0.0)
+            with jax.named_scope(SCOPE_PACK):
+                flat = [leaf.reshape(leaf.shape[0], -1).astype(jnp.float64)
+                        for leaf in jax.tree_util.tree_leaves(out)]
+                packed = (jnp.concatenate(flat, axis=1) if flat
+                          else jnp.zeros((bucket, 0)))
+                # masked lanes zero via where, NOT multiply: sort lanes
+                # hold -inf pads and -inf * 0 is NaN
+                return jnp.where(valid_b[:, None], packed, 0.0)
 
-        cached = (jax.jit(stacked), treedef, spec)
+        cached = (jax.jit(_named(stacked, f"qw_stacked_q{bucket}_k{k}")),
+                  treedef, spec)
         _STACKED_CACHE[key] = cached
     return cached
 
@@ -1217,16 +1271,18 @@ def dispatch_plan_stacked(plans, k: int, arrays_list, valid=None,
     if valid is None:
         valid = [True] * batch
     pad = bucket - batch
-    plans_b = list(plans) + [plans[-1]] * pad
-    arrays_b = list(arrays_list) + [arrays_list[-1]] * pad
-    valid_b = np.zeros(bucket, np.bool_)
-    valid_b[:batch] = list(valid)
-    shared_slots, stacked_slots = stacked_slot_split(plans_b)
-    scal_b, nd_b = _device_group_scalars(plans_b, use_cache=cache_scalars)
-    shared_arrays = tuple(arrays_b[0][s] for s in shared_slots)
-    lane_stacks = tuple(tuple(arrays_b[q][s] for q in range(bucket))
-                        for s in stacked_slots)
-    valid_dev = jax.device_put(valid_b)
+    with profiled_phase(PHASE_DISPATCH_PREPARE):
+        plans_b = list(plans) + [plans[-1]] * pad
+        arrays_b = list(arrays_list) + [arrays_list[-1]] * pad
+        valid_b = np.zeros(bucket, np.bool_)
+        valid_b[:batch] = list(valid)
+        shared_slots, stacked_slots = stacked_slot_split(plans_b)
+        scal_b, nd_b = _device_group_scalars(plans_b,
+                                             use_cache=cache_scalars)
+        shared_arrays = tuple(arrays_b[0][s] for s in shared_slots)
+        lane_stacks = tuple(tuple(arrays_b[q][s] for q in range(bucket))
+                            for s in stacked_slots)
+        valid_dev = jax.device_put(valid_b)
     profile = current_profile()
     recording = flight.recording()
     # shared once-per-dispatch cache key (see dispatch_plan)
@@ -1307,7 +1363,8 @@ def dispatch_plan(plan: LoweredPlan, k: int,
     the later blocking readback only waits out the remainder."""
     k = max(0, min(k, plan.num_docs_padded))
     SEARCH_KERNEL_LAUNCHES_TOTAL.inc()
-    scalars, num_docs = _device_scalars(plan)
+    with profiled_phase(PHASE_DISPATCH_PREPARE):
+        scalars, num_docs = _device_scalars(plan)
     args = (tuple(device_arrays), scalars, num_docs)
     profile = current_profile()
     recording = flight.recording()
@@ -1539,11 +1596,14 @@ def _mask_fill_fn(plan: LoweredPlan) -> Callable:
     eval_node = _node_evaluator(padded)
 
     def mask_fn(arrays, scalars, num_docs):
-        mask, _ = eval_node(root, arrays, scalars)
-        mask = mask & mask_ops.valid_docs_mask(num_docs, padded)
-        return _pack_mask(mask, padded)
+        # one outer scope: the fill's own predicate counts as mask_fill,
+        # not as a second term_mask / range_filter
+        with jax.named_scope(SCOPE_MASK_FILL):
+            mask, _ = eval_node(root, arrays, scalars)
+            mask = mask & mask_ops.valid_docs_mask(num_docs, padded)
+            return _pack_mask(mask, padded)
 
-    return mask_fn
+    return _named(mask_fn, "qw_mask_fill")
 
 
 # qwir R2 certification registry: functions in THIS module allowed to mint
@@ -1591,13 +1651,19 @@ def compute_packed_mask(
            tuple((a.shape, str(a.dtype)) for a in plan.arrays),
            tuple(str(s.dtype) for s in plan.scalars),
            padded)
-    fill = _MASK_FILL_CACHE.get(key)
-    if fill is None:
-        fill = jax.jit(_mask_fill_fn(plan))
-        _MASK_FILL_CACHE[key] = fill
-    scalars, num_docs = _device_scalars(plan)
-    SEARCH_KERNEL_LAUNCHES_TOTAL.inc()
-    packed = fill(tuple(device_arrays), scalars, num_docs)
-    # qwlint: disable-next-line=QW001 - deliberate padded/8-byte readback of
-    # the freshly computed mask into the host-side cache tier
-    return np.asarray(jax.device_get(packed), dtype=np.uint8), packed
+    # a second program and a blocking readback behind whatever the device
+    # has queued: the request that fills pays for it, so it is a phase
+    profile_add("mask_fills")
+    with profiled_phase(PHASE_MASK_FILL) as rec:
+        fill = _MASK_FILL_CACHE.get(key)
+        if rec is not None:
+            rec["compiled"] = fill is None
+        if fill is None:
+            fill = jax.jit(_mask_fill_fn(plan))
+            _MASK_FILL_CACHE[key] = fill
+        scalars, num_docs = _device_scalars(plan)
+        SEARCH_KERNEL_LAUNCHES_TOTAL.inc()
+        packed = fill(tuple(device_arrays), scalars, num_docs)
+        # qwlint: disable-next-line=QW001 - deliberate padded/8-byte readback
+        # of the freshly computed mask into the host-side cache tier
+        return np.asarray(jax.device_get(packed), dtype=np.uint8), packed

@@ -36,8 +36,8 @@ from ..observability.metrics import (
     SEARCH_PROFILED_QUERIES_TOTAL, SEARCH_TIMED_OUT_TOTAL,
 )
 from ..observability.profile import (
-    PHASE_FETCH_DOCS, PHASE_ROOT_MERGE, QueryProfile, current_profile,
-    profile_scope, profiled_phase,
+    PHASE_FETCH_DOCS, PHASE_ROOT_FINALIZE, PHASE_ROOT_MERGE, PHASE_ROOT_PLAN,
+    QueryProfile, current_profile, profile_scope, profiled_phase,
 )
 from ..observability import flight
 from ..observability.slo import SLO_TRACKER
@@ -364,6 +364,19 @@ class RootSearcher:
     def _search_traced(self, request: SearchRequest,
                        budget: QueryBudget) -> SearchResponse:
         t0 = time.monotonic()
+        with profiled_phase(PHASE_ROOT_PLAN) as rec:
+            collector, split_meta_by_id, nodes, dispatches = \
+                self._plan_dispatches(request)
+            if rec is not None:
+                rec["splits"] = len(split_meta_by_id)
+        responses = self._fan_out(dispatches, nodes, budget)
+        return self._merge_and_finish(request, budget, t0, collector,
+                                      split_meta_by_id, nodes, responses)
+
+    def _plan_dispatches(self, request: SearchRequest) -> tuple:
+        """Everything before the fan-out: index resolution, request
+        validation, split listing and pruning, job placement. Returns
+        (collector, split_meta_by_id, nodes, dispatches)."""
         indexes = self._resolve_indexes(request.index_ids)
         if not indexes:
             raise ValueError(f"no index matches {request.index_ids!r}")
@@ -425,8 +438,11 @@ class RootSearcher:
                     splits=[offsets[j.split_id] for j in node_jobs],
                 )
                 dispatches.append((node_id, leaf_request))
+        return collector, split_meta_by_id, nodes, dispatches
 
-        responses = self._fan_out(dispatches, nodes, budget)
+    def _merge_and_finish(self, request: SearchRequest, budget: QueryBudget,
+                          t0: float, collector, split_meta_by_id, nodes,
+                          responses) -> SearchResponse:
         # root merge covers only the post-join collector work: the fan-out
         # wall is already accounted inside each leaf's own phases, and an
         # umbrella phase here would double-count it against sum≈wall
@@ -460,10 +476,12 @@ class RootSearcher:
                 rec["docs"] = len(hits)
         aggregations = None
         if request.aggs:
-            aggregations = finalize_aggregations(merged.aggregation_states())
-            # ES returns the aggregation skeleton even when no split
-            # contributed states (empty index / zero matching splits)
-            _fill_empty_aggs(aggregations, request.aggs)
+            with profiled_phase(PHASE_ROOT_FINALIZE):
+                aggregations = finalize_aggregations(
+                    merged.aggregation_states())
+                # ES returns the aggregation skeleton even when no split
+                # contributed states (empty index / zero matching splits)
+                _fill_empty_aggs(aggregations, request.aggs)
         return SearchResponse(
             num_hits=merged.num_hits,
             hits=hits,

@@ -37,7 +37,9 @@ from ..observability.metrics import (
     SEARCH_SPLITS_DOWNGRADED_TOTAL, SEARCH_SPLITS_PRUNED_TOTAL,
 )
 from ..observability.profile import (
-    QueryProfile, current_profile, profile_scope,
+    PHASE_CACHE_FILL, PHASE_CACHE_LOOKUP, PHASE_LEAF_PREPARE,
+    PHASE_SPLIT_OPEN, QueryProfile, current_profile, profile_scope,
+    profiled_phase,
 )
 from ..query.ast import MatchAll
 from ..parallel.fanout import (
@@ -393,6 +395,25 @@ class SearchService:
 
     def _leaf_search_deadlined(self, request: LeafSearchRequest,
                                deadline: Deadline) -> LeafSearchResponse:
+        search_request = request.search_request
+        with profiled_phase(PHASE_LEAF_PREPARE) as rec:
+            (doc_mapper, splits, collector, prune_ctx, threshold, prune_stats,
+             num_pruned_by_predicate, pending) = self._triage_splits(request)
+            if rec is not None:
+                rec["splits"] = len(splits)
+                rec["pending"] = len(pending)
+        return self._search_pending(request, deadline, doc_mapper, splits,
+                                    collector, prune_ctx, threshold,
+                                    prune_stats, num_pruned_by_predicate,
+                                    pending)
+
+    def _triage_splits(self, request: LeafSearchRequest) -> tuple:
+        """Everything before the first split is prepared: the doc mapper,
+        the split order, and each split answered without the device where
+        it can be (metadata count, negative predicate cache, leaf cache,
+        agg tier, wire-seeded threshold). Returns (doc_mapper, splits,
+        collector, prune_ctx, threshold, prune_stats,
+        num_pruned_by_predicate, pending)."""
         doc_mapper = DocMapper.from_dict(request.doc_mapping)
         search_request = request.search_request
         splits = self._optimize_split_order(search_request, request.splits)
@@ -465,7 +486,14 @@ class SearchService:
                 else:
                     still_pending.append(split)
             pending = still_pending
+        return (doc_mapper, splits, collector, prune_ctx, threshold,
+                prune_stats, num_pruned_by_predicate, pending)
 
+    def _search_pending(self, request: LeafSearchRequest, deadline: Deadline,
+                        doc_mapper, splits, collector, prune_ctx, threshold,
+                        prune_stats, num_pruned_by_predicate,
+                        pending) -> LeafSearchResponse:
+        search_request = request.search_request
         offload_future = None
         offload_result: dict[str, Any] = {}
         offloaded: list[SplitIdAndFooter] = []
@@ -889,7 +917,8 @@ class SearchService:
         prepared = []
         for split in group:
             try:
-                reader = self.context.reader(split)
+                with profiled_phase(PHASE_SPLIT_OPEN):
+                    reader = self.context.reader(split)
                 cache = self.context.predicate_cache
                 if prune_ctx is not None and prune_ctx.mode == "score":
                     # remember df/max-tf at split open so future queries
@@ -902,8 +931,9 @@ class SearchService:
                 # admit→transfer→execute→release cycle runs alone — a whole
                 # group admitted up front could exceed the budget and
                 # starve itself
-                cache_ctx = self._consult_split_caches(search_request,
-                                                       split, reader)
+                with profiled_phase(PHASE_CACHE_LOOKUP):
+                    cache_ctx = self._consult_split_caches(search_request,
+                                                           split, reader)
                 plan = prepare_plan_only(
                     search_request, doc_mapper, reader, split.split_id,
                     absence_sink=lambda f, t, s=split.split_id:
@@ -1027,24 +1057,31 @@ class SearchService:
             # incomplete — skip the fill, never cache a partial mask
             from .executor import compute_packed_mask
             try:
+                # the fill program and its readback are the `mask_fill`
+                # phase (inside compute_packed_mask); the puts are below
                 host_packed, dev_packed = compute_packed_mask(
                     plan, device_arrays)
-                mask_cache.put(split.split_id, digest, host_packed)
-                store = self.context.resident_store
-                if (store is not None and owner is not None
-                        and getattr(owner, "_device_array_cache",
-                                    None) is not None):
-                    # seed the device copy under the SAME key a mask-hit
-                    # plan will stage (`mask.<digest>`): the next warm run
-                    # finds every array resident and uploads nothing.
-                    # Accounted in the store's byte stats (columns=0: the
-                    # mask is not a column miss); the padded/8 bytes ride
-                    # outside HbmBudget admission by design — they are
-                    # noise next to any column and admission could shed a
-                    # best-effort fill
-                    owner._device_array_cache[f"mask.{digest}"] = dev_packed
-                    store.note_upload(split.split_id,
-                                      int(dev_packed.nbytes), 0)
+                with profiled_phase(PHASE_CACHE_FILL) as rec:
+                    if rec is not None:
+                        rec["tier"] = "mask"
+                    mask_cache.put(split.split_id, digest, host_packed)
+                    store = self.context.resident_store
+                    if (store is not None and owner is not None
+                            and getattr(owner, "_device_array_cache",
+                                        None) is not None):
+                        # seed the device copy under the SAME key a
+                        # mask-hit plan will stage (`mask.<digest>`): the
+                        # next warm run finds every array resident and
+                        # uploads nothing. Accounted in the store's byte
+                        # stats (columns=0: the mask is not a column miss);
+                        # the padded/8 bytes ride outside HbmBudget
+                        # admission by design — they are noise next to any
+                        # column and admission could shed a best-effort
+                        # fill
+                        owner._device_array_cache[f"mask.{digest}"] = \
+                            dev_packed
+                        store.note_upload(split.split_id,
+                                          int(dev_packed.nbytes), 0)
             except (OverloadShed, TenantRateLimited):
                 raise
             except Exception as exc:  # noqa: BLE001 - fill is best-effort
@@ -1057,13 +1094,17 @@ class SearchService:
             # sound under threshold pushdown and search_after: the kernel
             # computes count/aggs from the FULL filter mask (executor.py);
             # only the hit list is eligibility-restricted
-            agg_cache.put_count(split.split_id, digest, response.num_hits)
-            for name in cache_ctx.get("agg_fill", ()):
-                state = response.intermediate_aggs.get(name)
-                spec = (request.aggs or {}).get(name)
-                if state is not None and spec is not None:
-                    agg_cache.put_agg(split.split_id, digest,
-                                      agg_shape_digest(spec), state)
+            with profiled_phase(PHASE_CACHE_FILL) as rec:
+                if rec is not None:
+                    rec["tier"] = "agg"
+                agg_cache.put_count(split.split_id, digest,
+                                    response.num_hits)
+                for name in cache_ctx.get("agg_fill", ()):
+                    state = response.intermediate_aggs.get(name)
+                    spec = (request.aggs or {}).get(name)
+                    if state is not None and spec is not None:
+                        agg_cache.put_agg(split.split_id, digest,
+                                          agg_shape_digest(spec), state)
         except (OverloadShed, TenantRateLimited):
             raise
         except Exception as exc:  # noqa: BLE001 - fill is best-effort
@@ -1231,9 +1272,13 @@ class SearchService:
                     # a threshold-pushdown response may have its hit list
                     # truncated below k — correct for THIS query's merge,
                     # poison for a future query with a lower threshold
-                    key = canonical_request_key(
-                        split.split_id, search_request, split.time_range)
-                    self.context.leaf_cache.put(key, response)
+                    with profiled_phase(PHASE_CACHE_FILL) as rec:
+                        if rec is not None:
+                            rec["tier"] = "leaf"
+                        key = canonical_request_key(
+                            split.split_id, search_request,
+                            split.time_range)
+                        self.context.leaf_cache.put(key, response)
                 collector.add_leaf_response(response)
                 if threshold is not None:
                     threshold.update(collector.sort_value_threshold())

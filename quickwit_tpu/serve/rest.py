@@ -66,7 +66,17 @@ _MAX_INFLATED_BYTES = 256 << 20  # gzip bodies inflate to at most 256 MiB
 
 _REQUEST_COUNTER = METRICS.counter("qw_http_requests_total", "HTTP requests")
 _REQUEST_LATENCY = METRICS.histogram("qw_http_request_duration_seconds",
-                                     "HTTP request latency")
+                                     "HTTP request latency, headers "
+                                     "parsed to last byte written, by route "
+                                     "(search|metrics|other)")
+
+
+def _route_label(path: str) -> str:
+    """Three fixed values, so that a search's handler time can be read
+    apart from the scrapes that read it."""
+    if path.endswith(("/search", "/_search", "/_msearch", "/search/stream")):
+        return "search"
+    return "metrics" if path == "/metrics" else "other"
 
 
 class ApiError(Exception):
@@ -1539,7 +1549,8 @@ def _make_handler(server: RestServer):
             self.end_headers()
             self.wfile.write(data)
             _REQUEST_COUNTER.inc(method=method, status=str(status))
-            _REQUEST_LATENCY.observe(time.monotonic() - t0)
+            _REQUEST_LATENCY.observe(time.monotonic() - t0,
+                                     route=_route_label(parsed.path))
 
         def do_GET(self):
             self._handle("GET")
