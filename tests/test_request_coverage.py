@@ -31,6 +31,7 @@ from quickwit_tpu.query.ast import Bool, Range, RangeBound, Term
 from quickwit_tpu.search import SearchRequest, SortField, executor
 from quickwit_tpu.search.batcher import (QueryBatcher, _PriorityLock,
                                          qbatch_enabled)
+from quickwit_tpu.search.chunkexec import CHUNKING
 from quickwit_tpu.search.leaf import prepare_plan_only, prepare_single_split
 from quickwit_tpu.search.models import LeafSearchRequest, SplitIdAndFooter
 from quickwit_tpu.search.service import SearcherContext, SearchService
@@ -100,6 +101,10 @@ def launches(monkeypatch):
     process's, and a worker's earlier tests may still have threads about."""
     mine, me = [], threading.get_ident()
     real = SEARCH_KERNEL_LAUNCHES_TOTAL.inc
+    # one fused program per dispatch: a worker whose earlier tests chunked
+    # has taught the process-wide sizer a span, and a scan then launches one
+    # program per chunk (tests/test_chunked_execution.py before this file)
+    monkeypatch.setattr(CHUNKING, "enabled", False)
 
     def inc(*args, **labels):
         if threading.get_ident() == me:

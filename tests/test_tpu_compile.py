@@ -66,6 +66,12 @@ REQUESTS = {
         sort_fields=(SortField("timestamp", "desc"),)),
     "agg_only": SearchRequest(index_ids=["hdfs-logs"], query_ast=ERROR,
                               max_hits=0, aggs=AGGS),
+    # the sketch's one-hot product: a scanned dot_general over doc chunks
+    "agg_percentiles": SearchRequest(
+        index_ids=["hdfs-logs"], max_hits=0,
+        query_ast=Bool(must=(ERROR,), filter=(_window(1, 4),)),
+        aggs={"tenants": {"percentiles": {"field": "tenant_id",
+                                          "percents": [50, 95, 99]}}}),
 }
 
 

@@ -167,6 +167,7 @@ def _aggs():
                          interval_micros=3_600 * 10**6,
                          sub_metrics=(MetricAgg("lat_avg", "avg", "latency"),)),
         MetricAgg("lat_stats", "stats", "latency"),
+        MetricAgg("lat_pctl", "percentiles", "latency"),
     ]
 
 
