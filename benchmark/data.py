@@ -1,12 +1,14 @@
 """Data, made from the seed: one synthetic hdfs split per split of the
-configuration, generated by a spawned CPU worker each, kept under
-`<checkout>/.bench_cache/<config>/<seed>/` (git-ignored; a seed's splits are
-made once per checkout and found again by every later run and cell), and
-published through the metastore protocol before the node starts.
+configuration, generated by a spawned CPU worker each, one at a time, kept
+under `<checkout>/.bench_cache/<config>/<seed>/` (git-ignored; a seed's
+splits are made once per checkout and found again by every later run and
+cell; the bodies, which no seed changes, under `.bench_cache/bodies/` for
+every configuration of the same shapes), and published through the metastore
+protocol before the node starts.
 
 The documents are the benchmark's: `draw_docs` and `body_tokens` draw every
 document's timestamp, tenant, severity and body terms, and keep them beside
-the split (`*.docs.npz`, `body-*.tokens.npy`) for the reference, which reads
+the split (`*.docs.npz`, `bodies/body-*.tokens.npy`) for the reference, which reads
 nothing else. `build_split` hands those documents to the program's array
 writers (as `index/synthetic.py::synthetic_hdfs_split` does) to make the
 split file the node serves; `generate_split`, the workers and
@@ -20,6 +22,7 @@ import ctypes
 import json
 import multiprocessing
 import os
+import resource
 import shutil
 import signal
 import time
@@ -28,7 +31,11 @@ import numpy as np
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CACHE_DIR = os.path.join(ROOT, ".bench_cache")
-KEEP_SEEDS = 6      # of one configuration; older ones are deleted
+# A configuration's newest seeds are kept up to this many bytes, and never
+# fewer than KEEP_SEEDS_MIN: six 10M-doc seeds were 9.7 GB, six 40M-doc
+# seeds would be 39 GB. The bodies (once per checkout) are kept as they are.
+KEEP_BYTES = 14_000_000_000
+KEEP_SEEDS_MIN = 2
 
 
 MAGIC = b"QWTPU001"
@@ -57,6 +64,11 @@ class SplitFile:
         offsets = self.array(f"{prefix}_offsets")
         return [blob[offsets[i]:offsets[i + 1]].decode()
                 for i in range(len(offsets) - 1)]
+
+
+def rss_peak_bytes() -> int:
+    """This process's peak resident size (`ru_maxrss`; Linux counts KiB)."""
+    return 1024 * resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
 
 
 def die_with_parent() -> None:
@@ -92,22 +104,29 @@ class DrawnTokens:
         return self.draws
 
 
-def body_path(config_dir: str, num_docs: int, body_seed: int) -> str:
-    return os.path.join(config_dir, f"body-{num_docs}-{body_seed}")
+def body_path(body_dir: str, config: dict, number: int) -> str:
+    """Where the body of a configuration's split `number` is kept, named by
+    all that it is drawn from, so that configurations of the same `assumed`
+    shapes share it (split 0 of `hdfs-logs-40m` is `hdfs-logs-10m`'s)."""
+    assumed = config["assumed"]
+    return os.path.join(body_dir, "body-{}-{}-{}-{}-{}".format(
+        config["docs_per_split"], assumed["body_vocabulary_terms"],
+        assumed["body_zipf_exponent"], assumed["body_tokens_per_doc"],
+        assumed["body_seed"] + number))
 
 
-def body_arrays(config_dir: str, config: dict, num_docs_padded: int,
-                body_seed: int) -> tuple:
+def body_arrays(body_dir: str, config: dict, num_docs_padded: int,
+                number: int) -> tuple:
     """(arrays, field metadata) of the body field. The benchmark draws each
     document's `body_tokens_per_doc` terms, Zipf over the vocabulary, from
     `body_seed` and keeps them (`body-*.tokens.npy`, [docs, tokens]) for
     the reference; the program's `_write_body` indexes that draw. Both are
-    kept under the configuration's cache directory: the body is the same
-    for every `--seed` (see build_split) and is four fifths of a split's
-    generation."""
+    kept under `.bench_cache/bodies`: the body is the same for every
+    `--seed` (see build_split) and is four fifths of a split's generation."""
     from quickwit_tpu.index import synthetic
     assumed, num_docs = config["assumed"], config["docs_per_split"]
-    path = body_path(config_dir, num_docs, body_seed)
+    path = body_path(body_dir, config, number)
+    body_seed = assumed["body_seed"] + number
     if os.path.exists(path + ".json"):
         with open(path + ".json") as fh:
             fields = json.load(fh)
@@ -150,7 +169,7 @@ def draw_docs(config: dict, seed: int) -> dict:
 
 
 def build_split(config: dict, docs: dict, number: int,
-                config_dir: str) -> bytes:
+                body_dir: str) -> bytes:
     """One hdfs-logs split over the benchmark's documents, as
     `index/synthetic.py::synthetic_hdfs_split` builds it (same writers, same
     arrays, doc store on). Two things keep every array's SHAPE the same for
@@ -194,8 +213,8 @@ def build_split(config: dict, docs: dict, number: int,
                                  synthetic.SEVERITIES, docs["sev"], num_docs,
                                  num_docs_padded)
 
-    arrays, body_fields = body_arrays(config_dir, config, num_docs_padded,
-                                      assumed["body_seed"] + number)
+    arrays, body_fields = body_arrays(body_dir, config, num_docs_padded,
+                                      number)
     for name, array in arrays.items():
         builder.add_array(name, array)
     fields.update(body_fields)
@@ -210,13 +229,16 @@ def build_split(config: dict, docs: dict, number: int,
 
 
 def generate_split(path: str, config: dict, seed: int, number: int,
-                   config_dir: str) -> None:
+                   body_dir: str) -> None:
     """Worker: one split written where the index's storage will look for
     it, and beside it what its SplitMetadata needs."""
     die_with_parent()
     started = time.monotonic()
+    body = body_path(body_dir, config, number)
+    body_files = [] if os.path.exists(body + ".json") else [
+        body + end for end in (".tokens.npy", ".npz", ".json")]
     docs = draw_docs(config, seed)
-    data = build_split(config, docs, number, config_dir)
+    data = build_split(config, docs, number, body_dir)
     os.makedirs(os.path.dirname(path), exist_ok=True)
     np.savez(path + ".docs.npz", **docs)
     with open(path + ".tmp", "wb") as fh:
@@ -228,11 +250,15 @@ def generate_split(path: str, config: dict, seed: int, number: int,
     with open(path + ".json", "w") as fh:
         json.dump({"path": path, "num_docs": config["docs_per_split"],
                    "bytes": len(data), "docs": path + ".docs.npz",
-                   "body_tokens": body_path(
-                       config_dir, config["docs_per_split"],
-                       config["assumed"]["body_seed"] + number)
-                   + ".tokens.npy",
-                   "seconds": round(time.monotonic() - started, 1)}, fh)
+                   "body_tokens": body + ".tokens.npy",
+                   "seconds": round(time.monotonic() - started, 1),
+                   # this worker's own peak, and what it left on the
+                   # disk: the split, its documents and, where it had to
+                   # make it, the body
+                   "ru_maxrss_bytes": rss_peak_bytes(),
+                   "wrote_bytes": len(data) + sum(
+                       os.path.getsize(made) for made in
+                       [path + ".docs.npz"] + body_files)}, fh)
 
 
 def index_root(config: dict, seed: int) -> str:
@@ -241,26 +267,29 @@ def index_root(config: dict, seed: int) -> str:
 
 
 def split_jobs(config: dict, seed: int) -> list:
-    """[(path, configuration, split seed, split number, the configuration's
-    cache directory)] of a configuration's splits."""
+    """[(path, configuration, split seed, split number, the directory of the
+    bodies)] of a configuration's splits."""
     root = os.path.join(index_root(config, seed), config["index_id"])
     return [(os.path.join(root, f"hdfs-{seed + i}.split"), config, seed + i,
-             i, os.path.join(CACHE_DIR, config["name"]))
+             i, os.path.join(CACHE_DIR, "bodies"))
             for i in range(config["num_splits"])]
 
 
 def ensure_splits(config: dict, seed: int, started: list, say) -> list:
     """The configuration's splits for this seed, generated where the cache
-    has none. `started` collects worker processes for the reaper. Returns
-    each split's record, with `cached` saying where it came from."""
+    has none by one worker at a time, so that the generator's host memory
+    does not grow with `num_splits` (ISSUE 28): a worker that has to make its
+    split's body peaks at 12.95 GB at 10M docs, one that finds it kept at
+    4.68 GB (PR 28's chip runs, README.md). Fixed here, not read from the
+    machine, no option. `started` collects worker processes for the reaper.
+    Returns each split's record, with `cached` saying where it came from."""
     jobs = split_jobs(config, seed)
     missing = [job for job in jobs if not os.path.exists(job[0] + ".json")]
     ctx = multiprocessing.get_context("spawn")
-    procs = [ctx.Process(target=generate_split, args=job) for job in missing]
-    for proc in procs:
+    for job in missing:
+        proc = ctx.Process(target=generate_split, args=job)
         proc.start()
-    started += procs
-    for proc, job in zip(procs, missing):
+        started.append(proc)
         proc.join()
         if proc.exitcode != 0:
             raise RuntimeError(f"the generator of {job[0]} exited with "
@@ -275,18 +304,44 @@ def ensure_splits(config: dict, seed: int, started: list, say) -> list:
         say(f"[data] {os.path.basename(job[0])}: {record['num_docs']} docs, "
             f"{record['bytes']} bytes, " + (
                 "found in the cache" if record["cached"] else
-                f"made from its seed in {record['seconds']}s"))
+                f"made from its seed in {record['seconds']}s, wrote "
+                f"{record['wrote_bytes']} bytes, worker's peak "
+                f"{record['ru_maxrss_bytes'] / 1e6:.0f} MB"))
+    made = [s for s in splits if not s["cached"]]
+    if made:
+        say(f"[data] {len(made)} split(s) made, one worker at a time: "
+            f"{sum(s['wrote_bytes'] for s in made)} bytes written under "
+            ".bench_cache")
     os.utime(os.path.dirname(index_root(config, seed)))     # newest seed
     evict(config["name"])
     return splits
 
 
+def generator_peak(splits: list):
+    """The largest peak of this run's generator workers, which ran one at a
+    time; None where every split was found."""
+    return max((s["ru_maxrss_bytes"] for s in splits if not s["cached"]),
+               default=None)
+
+
+def tree_bytes(path: str) -> int:
+    return sum(os.path.getsize(os.path.join(folder, name))
+               for folder, _, names in os.walk(path) for name in names)
+
+
 def evict(config_name: str) -> None:
+    """Delete a configuration's older seeds: the newest stay, as many as
+    fit into KEEP_BYTES and never fewer than KEEP_SEEDS_MIN."""
     root = os.path.join(CACHE_DIR, config_name)
     seeds = sorted((os.path.join(root, d) for d in os.listdir(root)
-                    if d.isdigit()), key=os.path.getmtime)
-    for old in seeds[:-KEEP_SEEDS]:
-        shutil.rmtree(old, ignore_errors=True)
+                    if d.isdigit()), key=os.path.getmtime, reverse=True)
+    held = 0
+    for number, folder in enumerate(seeds):
+        held += tree_bytes(folder)
+        if number >= KEEP_SEEDS_MIN and held > KEEP_BYTES:
+            for old in seeds[number:]:
+                shutil.rmtree(old, ignore_errors=True)
+            return
 
 
 def publish_splits(node_config: dict, index_id: str, splits: list) -> None:

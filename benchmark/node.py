@@ -48,6 +48,7 @@ class NodeProcess:
         NodeProcess.started.append(self)
         self.endpoint = None
         self.device = None
+        self.rss_peak_bytes = None      # the node's own report as it stops
 
     @staticmethod
     def _in_child() -> None:
@@ -144,6 +145,8 @@ class NodeProcess:
             raise NodeFailure(f"the node exited with {self.proc.returncode}"
                               "\n" + self.log_tail())
         line = self.wait_line(" stopped; device memory: ", 5)
+        self.rss_peak_bytes = int(self.wait_line(
+            "host rss peak bytes=", 5).rpartition("=")[2])
         return json.loads(line.rpartition("device memory: ")[2])
 
 

@@ -4,6 +4,10 @@ the process that holds the chip.
 
     python benchmark/node_main.py --config node.yaml [--trace-dir DIR]
 
+As it ends it prints its own peak resident size (`host rss peak bytes=<n>`,
+`ru_maxrss`): the harness cannot read a child's peak while it lives, since
+the chip machine's `/proc/<pid>/status` carries no `VmHWM`.
+
 With `--trace-dir`, a control thread watches for the files `<DIR>.start` and
 `<DIR>.stop`, which the harness creates: the first starts a trace into DIR,
 the second stops it; each prints one line the harness waits for. (`cmd_run`'s
@@ -14,6 +18,7 @@ hands a signal to.)
 from __future__ import annotations
 
 import os
+import resource
 import sys
 import threading
 import time
@@ -51,7 +56,12 @@ def main(argv: list) -> int:
         trace_on_request(argv[at + 1])
         argv = argv[:at] + argv[at + 2:]
     from quickwit_tpu.cli import main as cli_main
-    return cli_main(argv + ["run"])
+    try:
+        return cli_main(argv + ["run"])
+    finally:
+        print("host rss peak bytes="      # Linux counts ru_maxrss in KiB
+              f"{1024 * resource.getrusage(resource.RUSAGE_SELF).ru_maxrss}",
+              flush=True)
 
 
 if __name__ == "__main__":

@@ -93,9 +93,14 @@ def load_cell(name: str) -> dict:
     with open(os.path.join(ROOT, entry["file"])) as fh:
         cell["config_file"] = json.load(fh)
     cell["mix"] = traffic.load_mix(cell["traffic"])
-    for kind in ("end_to_end", "per_layer"):
-        cell[kind] = [m for m in manifest[kind]
-                      if name in m.get("workloads", [name])]
+    # a metric that lists its cells is theirs alone; a per-layer metric that
+    # lists none is every cell's that reports the end-to-end metric it moves
+    cell["end_to_end"] = [m for m in manifest["end_to_end"]
+                          if name in m.get("workloads", [name])]
+    ends = {m["name"] for m in cell["end_to_end"]}
+    cell["per_layer"] = [m for m in manifest["per_layer"]
+                         if name in m.get("workloads", [name])
+                         and m["moves"] in ends]
     return cell
 
 
@@ -239,6 +244,11 @@ def warm_up(node, index, mix, seed: int) -> None:
             was = group_buckets(before)
             seen = {b: now[b] - was.get(b, 0.0) for b in now}
             if all(seen.get(float(b), 0) > 0 for b in buckets):
+                break
+            if round_no == 0 and not any(seen.values()):
+                # the first round stacked nothing at all: the deployment
+                # does not stack this shape (one fused program over its
+                # splits takes it), and further rounds would warm nothing up
                 break
         say(f"[warm-up] {shape} bursts for group buckets {buckets}: "
             f"{time.monotonic() - began:.1f}s, compile misses {misses}, "
@@ -414,6 +424,18 @@ def measure(args, cell: dict, node_env: dict, workers: list,
     os.makedirs(RUN_DIR)
 
     splits = data.ensure_splits(config, args.seed, workers, say)
+    # host memory: each process's own peak, and in `together` the sums of
+    # those that were alive at one time (this process's peak so far stands
+    # for what it held then)
+    generator = data.generator_peak(splits)
+    together = [(generator or 0) + data.rss_peak_bytes()]
+    node_peaks = []
+
+    def stop(node: NodeProcess) -> list:
+        memory = node.stop()
+        node_peaks.append(node.rss_peak_bytes)
+        together.append(node.rss_peak_bytes + data.rss_peak_bytes())
+        return memory
     node_config = {
         "node_id": "bench",
         "metastore_uri": f"file://{RUN_DIR}/metastore",
@@ -460,7 +482,7 @@ def measure(args, cell: dict, node_env: dict, workers: list,
         # window gets a node of its own, as a run on a cached split does
         node, _ = warm_node("node-settling.log")
         settle(node, config["index_id"], mix, args.seed, max(written))
-        node.stop()
+        stop(node)
         say("[node] the settling node has stopped; the window gets its own")
     node, device = warm_node("node.log", os.path.join(RUN_DIR, "trace")
                              if args.trace else None)
@@ -488,7 +510,7 @@ def measure(args, cell: dict, node_env: dict, workers: list,
         trace_done.result()
         tracer.shutdown()
     after = node.metrics()
-    memory = node.stop()
+    memory = stop(node)
     say(f"[node] stopped; device memory: {json.dumps(memory)}")
     parse(records)
 
@@ -533,13 +555,24 @@ def measure(args, cell: dict, node_env: dict, workers: list,
         metrics = end_to_end(run, args.seconds, setup_s)
     units = {m["name"]: m["unit"]
              for m in cell["end_to_end"] + cell["per_layer"]}
+    # an end-to-end metric that lists its cells is left out of the others
     result["metrics"] = {name: {"value": value, "unit": units[name]}
-                         for name, value in metrics.items()}
+                         for name, value in metrics.items() if name in units}
     result["device"] = device_line
     # the reference runs last: the node is gone and its peak has been read
     checks = check(cell, records, splits, args.seed)
     result["correct"] = all(c["value"] <= c["limit"] for c in checks.values())
     result["checks"] = checks
+    own = data.rss_peak_bytes()
+    together.append(own)
+    say("[host] rss peak: generator "
+        + (f"{generator / 1e6:.0f} MB (1 at once)" if generator
+           else "none (every split found)")
+        + f", node {max(node_peaks) / 1e6:.0f} MB, run {own / 1e6:.0f} MB; "
+        f"most at one time {max(together) / 1e6:.0f} MB")
+    say(f"[data] the run's own directory under .bench_cache (node logs, "
+        f"metastore, trace) holds {data.tree_bytes(RUN_DIR)} bytes")
+    device_line["host_rss_peak_bytes"] = max(together)
     result["_window"] = (records, splits)   # for the control; not printed
     return result
 

@@ -107,6 +107,7 @@ def main(argv: list) -> int:
         if trace:
             flat["busy_s"] = record["device"].get("busy_s")
         flat["memory_peak_bytes"] = record["device"]["memory_peak_bytes"]
+        flat["host_rss_peak_bytes"] = record["device"]["host_rss_peak_bytes"]
         for name, value in flat.items():
             table.setdefault((workload, trace, name), []).append(value)
     for (workload, trace, name), values in sorted(table.items()):
