@@ -426,6 +426,14 @@ SEARCH_KERNEL_LAUNCHES_TOTAL = METRICS.counter(
     "Device program launches of the served path: solo, multi-query, stacked, "
     "mask-fill (search/executor.py) and the fused batch and query-group "
     "families (parallel/fanout.py)")
+# One observation a leaf group of more than one split that ran per split:
+# how many of its splits' programs were launched together
+# (search/service.py::_execute_per_split). A group of one is not observed.
+SPLIT_WAVE_WIDTH = METRICS.histogram(
+    "qw_leaf_split_wave_width",
+    "Splits of one leaf group whose per-split programs were launched "
+    "together (groups of more than one split)",
+    buckets=(2.0, 4.0, 8.0, 16.0))
 
 # --- chaos / fault injection (common/faults.py) ----------------------------
 # Every fault the injector actually fired, labeled op=<operation>

@@ -6,9 +6,10 @@ Role of the reference's `SearchService` trait + `SearchServiceImpl`
 (`leaf.rs:1497,1887`):
 
 - `leaf_search`: search a batch of splits of one index on this node — split
-  reordering for pruning (`CanSplitDoBetter`), leaf cache, batched mesh
-  execution when the plan is split-uniform, per-split fallback otherwise,
-  partial failure collection.
+  reordering for pruning (`CanSplitDoBetter`), leaf cache, one fused
+  collective program over a group of splits where the node's devices form a
+  mesh and the plan is split-uniform, otherwise the group's per-split
+  programs launched together, partial failure collection.
 - `fetch_docs`: phase-2 doc fetch + snippet generation.
 
 The SearcherContext owns the caches (reader/hotcache byte ranges + device
@@ -35,6 +36,7 @@ from ..models.doc_mapper import DocMapper
 from ..observability.metrics import (
     SEARCH_DEADLINE_REMAINING, SEARCH_SHED_TOTAL,
     SEARCH_SPLITS_DOWNGRADED_TOTAL, SEARCH_SPLITS_PRUNED_TOTAL,
+    SPLIT_WAVE_WIDTH,
 )
 from ..observability.profile import (
     PHASE_CACHE_FILL, PHASE_CACHE_LOOKUP, PHASE_LEAF_PREPARE,
@@ -257,8 +259,8 @@ class SearcherContext:
         """A 2D ("splits", "docs") mesh sized to shard `n_splits` across
         this host's accelerators, or None when the batch cannot shard —
         single device, single split, or no axis size >1 divides the batch.
-        The None degenerate IS the seed single-device dispatch (host root
-        merge), kept as the explicit fallback path.
+        Without a mesh the service runs the group's per-split programs
+        together and merges on the host (`_execute_per_split`).
 
         The splits axis takes the largest size ≤ ndev that divides the
         batch; leftover devices fold into the docs axis (largest power of
@@ -832,7 +834,13 @@ class SearchService:
         # truncation; the per-split path handles those (2-key sorts ride
         # the batch via the lexicographic cross-split re-top-k)
         import json as _json
-        if (len(run_group) > 1 and not search_request.search_after
+        # the fused program is the mesh's: its cross-split merge runs as
+        # collectives over the devices. One device has no collective to
+        # amortise, and stacking the splits there costs more than their
+        # programs launched together (_execute_per_split) — so without a
+        # mesh the group goes per split and no host stack is ever made
+        mesh = self.context.device_mesh(len(run_group))
+        if (mesh is not None and not search_request.search_after
                 and string_sort_of(search_request, doc_mapper) is None
                 and not self._split_caches_route_per_split(search_request)
                 and not any(key in _json.dumps(search_request.aggs or {})
@@ -862,16 +870,14 @@ class SearchService:
                     absence_sink=self.context.predicate_cache
                     .record_term_absent,
                     sort_value_threshold=push_thr)
-                # the mesh is fixed at staging time: arrays committed for
-                # one sharding must not feed an executor traced for another
-                mesh = self.context.device_mesh(batch.n_splits)
-                # per-DEVICE admission: each chip pins only its shard of
-                # the stacks; column-family bytes are admitted under the
-                # mesh-resident stack owner inside stage_device_inputs
-                # (and stay warm), so exclude them here when that store
-                # will take them
-                stack_store = (self.context.resident_store
-                               if mesh is not None else None)
+                # the mesh was fixed above, before staging: arrays committed
+                # for one sharding must not feed an executor traced for
+                # another. per-DEVICE admission: each chip pins only its
+                # shard of the stacks; column-family bytes are admitted
+                # under the mesh-resident stack owner inside
+                # stage_device_inputs (and stay warm), so exclude them here
+                # when that store will take them
+                stack_store = self.context.resident_store
                 admitted = self.context.hbm_budget.admit(
                     batch, per_device_bytes(
                         batch, mesh,
@@ -1198,36 +1204,44 @@ class SearchService:
     def _execute_per_split(self, data, doc_mapper, search_request, collector,
                            prune_ctx=None, threshold=None,
                            prune_stats=None) -> None:
-        from .leaf import warmup_device_arrays
+        """The group's prepared splits as one wave: every split that is
+        still to run launches at once, each on a worker of its own, so no
+        program waits for another split's readback before it is enqueued
+        on the device. The calling thread then merges the outcomes in
+        split-id order and publishes the threshold once for the group, as
+        the fused route does. A group of one runs here, on the calling
+        thread."""
         deadline = current_deadline()
         cancel = current_cancel_token()
         profile = current_profile()
-        for split, reader, plan, prep_error, cache_ctx in data:
+        # one outcome a split: a LeafSearchResponse or a SplitSearchError
+        outcomes: list = []
+        wave: list[int] = []
+        for item in data:
+            split, _reader, _plan, prep_error, _cache_ctx = item
+            outcome = None
             if cancel is not None and cancel.cancelled:
-                # cancelled between splits: unexecuted splits are reported
+                # cancelled before the wave: unexecuted splits are reported
                 # as non-retryable cancel failures (the root must not spend
                 # its retry pool re-running work the caller abandoned)
-                collector.failed_splits.append(SplitSearchError(
+                outcome = SplitSearchError(
                     split_id=split.split_id,
                     error=f"query cancelled before split executed"
                           f"{': ' + cancel.reason if cancel.reason else ''}",
-                    retryable=False))
-                continue
-            if deadline is not None and deadline.expired:
+                    retryable=False)
+            elif deadline is not None and deadline.expired:
                 if profile is not None:
                     profile.mark_partial("shed: split execute")
-                collector.failed_splits.append(SplitSearchError(
+                outcome = SplitSearchError(
                     split_id=split.split_id,
                     error="deadline exceeded before split executed at leaf",
-                    retryable=True))
-                continue
-            if prep_error is not None:
+                    retryable=True)
+            elif prep_error is not None:
                 _warn_split_failure("prepare", split.split_id, prep_error)
-                collector.failed_splits.append(SplitSearchError(
+                outcome = SplitSearchError(
                     split_id=split.split_id, error=str(prep_error),
-                    retryable=True))
-                continue
-            if (prune_ctx is not None and prune_ctx.mode is not None
+                    retryable=True)
+            elif (prune_ctx is not None and prune_ctx.mode is not None
                     and threshold is not None
                     and not search_request.count_hits_exact):
                 # execute-time re-check: the threshold may have risen past
@@ -1241,68 +1255,128 @@ class SearchService:
                         if prune_stats is not None:
                             prune_stats["pruned"] += 1
                         SEARCH_SPLITS_PRUNED_TOTAL.inc()
-                        collector.add_leaf_response(LeafSearchResponse(
+                        outcome = LeafSearchResponse(
                             num_hits=0, num_attempted_splits=1,
-                            num_successful_splits=1))
-                        continue
-            admitted = 0
-            warmed = False
-            owner = reader
+                            num_successful_splits=1)
+            if outcome is None:
+                wave.append(len(outcomes))
+            outcomes.append(outcome)
+
+        def run(slot: int) -> None:
             try:
-                device_arrays, admitted, owner = warmup_device_arrays(
-                    reader, plan, self.context.hbm_budget,
-                    store=self.context.resident_store,
-                    split_id=split.split_id)
-                warmed = True
-                response = execute_prepared_split(
-                    search_request, doc_mapper, reader, split.split_id,
-                    plan, device_arrays,
-                    batcher=self.context.query_batcher,
-                    threshold_box=threshold,
-                    fault_injector=self.context.fault_injector)
-                if cache_ctx is not None and cache_ctx["agg_hits"]:
-                    # Tier B hits join the response BEFORE the leaf-cache
-                    # put and the merge — the cached LeafSearchResponse
-                    # must be complete, and the collector merges by name
-                    response.intermediate_aggs.update(cache_ctx["agg_hits"])
-                self._fill_split_caches(search_request, split, plan,
-                                        device_arrays, response, cache_ctx,
-                                        owner=owner)
-                if plan.threshold_slot < 0:
-                    # a threshold-pushdown response may have its hit list
-                    # truncated below k — correct for THIS query's merge,
-                    # poison for a future query with a lower threshold
-                    with profiled_phase(PHASE_CACHE_FILL) as rec:
-                        if rec is not None:
-                            rec["tier"] = "leaf"
-                        key = canonical_request_key(
-                            split.split_id, search_request,
-                            split.time_range)
-                        self.context.leaf_cache.put(key, response)
-                collector.add_leaf_response(response)
-                if threshold is not None:
-                    threshold.update(collector.sort_value_threshold())
-            except (OverloadShed, TenantRateLimited):
+                outcomes[slot] = self._search_prepared_split(
+                    data[slot], doc_mapper, search_request, threshold)
+            # qwlint: disable-next-line=QW004 - whole-query backpressure
+            # crosses the thread hop as a value and is re-raised below
+            except (OverloadShed, TenantRateLimited) as exc:
+                outcomes[slot] = exc
+
+        if len(wave) == 1:
+            run(wave[0])
+        elif wave:
+            SPLIT_WAVE_WIDTH.observe(len(wave))
+            if profile is not None:
+                profile.set_counter("split_wave_width", float(len(wave)))
+            # a worker's span stack is empty: capture the traceparent HERE
+            # so each split's spans join this query's trace (same capture
+            # as the offload dispatch and root _fan_out)
+            wave_tp = TRACER.current_traceparent()
+
+            def run_traced(slot: int) -> None:
+                with TRACER.span("leaf_split",
+                                 {"split_id": data[slot][0].split_id},
+                                 remote_parent=wave_tp):
+                    run(slot)
+
+            # run_with_context: the workers must see the query's deadline,
+            # tenant, profile and cancel token (one snapshot, replayed into
+            # a fresh context by every worker)
+            target = run_with_context(run_traced)
+            workers = [sync.thread(target=target, args=(slot,),
+                                   name="leaf-split-wave", daemon=True)
+                       for slot in wave]
+            for worker in workers:
+                worker.start()
+            for worker in workers:
+                worker.join()
+        for _item, outcome in sorted(zip(data, outcomes),
+                                     key=lambda pair: pair[0][0].split_id):
+            if isinstance(outcome, (OverloadShed, TenantRateLimited)):
                 # a shed/rate-limited tenant is rejected as a WHOLE query
                 # (429 + Retry-After at the API layer) — recording it as a
                 # retryable split failure would make the root burn retries
-                # on work the controller just refused
-                raise
-            except CancelledQuery as exc:
-                # NEVER retryable: the caller asked for the query to stop.
-                # Remaining splits fall out at the top-of-loop cancel check.
-                collector.failed_splits.append(SplitSearchError(
-                    split_id=split.split_id, error=str(exc), retryable=False))
-            except Exception as exc:  # noqa: BLE001 - partial failure semantics
-                _warn_split_failure("search", split.split_id, exc)
-                collector.failed_splits.append(SplitSearchError(
-                    split_id=split.split_id, error=str(exc), retryable=True))
-            finally:
-                if warmed:  # failed warmups release their own pins
-                    # releasing against the residency OWNER (not the reader)
-                    # is what moves the pins to resident instead of freeing
-                    # them: the owner carries `_device_array_cache`
-                    self.context.hbm_budget.release(owner, admitted)
+                # on work the controller just refused. Every worker has
+                # returned its pins by now.
+                raise outcome
+            if isinstance(outcome, SplitSearchError):
+                collector.failed_splits.append(outcome)
+            else:
+                collector.add_leaf_response(outcome)
+        if threshold is not None:
+            # monotone: a group that merged nothing publishes what stood
+            threshold.update(collector.sort_value_threshold())
+
+    def _search_prepared_split(self, item, doc_mapper, search_request,
+                               threshold):
+        """One split's unit of the wave, on whichever thread runs it:
+        warm-up → execute (through the query batcher, so same-split queries
+        of other requests still stack) → Tier A/B fills → leaf-cache put,
+        with the pins returned before it ends. Gives the split's response,
+        or its failure as a SplitSearchError; whole-query backpressure
+        (OverloadShed, TenantRateLimited) is raised."""
+        from .leaf import warmup_device_arrays
+        split, reader, plan, _prep_error, cache_ctx = item
+        admitted = 0
+        warmed = False
+        owner = reader
+        try:
+            device_arrays, admitted, owner = warmup_device_arrays(
+                reader, plan, self.context.hbm_budget,
+                store=self.context.resident_store,
+                split_id=split.split_id)
+            warmed = True
+            response = execute_prepared_split(
+                search_request, doc_mapper, reader, split.split_id,
+                plan, device_arrays,
+                batcher=self.context.query_batcher,
+                threshold_box=threshold,
+                fault_injector=self.context.fault_injector)
+            if cache_ctx is not None and cache_ctx["agg_hits"]:
+                # Tier B hits join the response BEFORE the leaf-cache
+                # put and the merge — the cached LeafSearchResponse
+                # must be complete, and the collector merges by name
+                response.intermediate_aggs.update(cache_ctx["agg_hits"])
+            self._fill_split_caches(search_request, split, plan,
+                                    device_arrays, response, cache_ctx,
+                                    owner=owner)
+            if plan.threshold_slot < 0:
+                # a threshold-pushdown response may have its hit list
+                # truncated below k — correct for THIS query's merge,
+                # poison for a future query with a lower threshold
+                with profiled_phase(PHASE_CACHE_FILL) as rec:
+                    if rec is not None:
+                        rec["tier"] = "leaf"
+                    key = canonical_request_key(
+                        split.split_id, search_request,
+                        split.time_range)
+                    self.context.leaf_cache.put(key, response)
+            return response
+        except (OverloadShed, TenantRateLimited):
+            raise
+        except CancelledQuery as exc:
+            # NEVER retryable: the caller asked for the query to stop
+            return SplitSearchError(
+                split_id=split.split_id, error=str(exc), retryable=False)
+        except Exception as exc:  # noqa: BLE001 - partial failure semantics
+            _warn_split_failure("search", split.split_id, exc)
+            return SplitSearchError(
+                split_id=split.split_id, error=str(exc), retryable=True)
+        finally:
+            if warmed:  # failed warmups release their own pins
+                # releasing against the residency OWNER (not the reader)
+                # is what moves the pins to resident instead of freeing
+                # them: the owner carries `_device_array_cache`
+                self.context.hbm_budget.release(owner, admitted)
 
     @staticmethod
     def _optimize_split_order(request: SearchRequest,
