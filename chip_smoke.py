@@ -765,7 +765,7 @@ def describe(record: dict) -> str:
 
 
 def hdfs_shapes() -> list:
-    """The benchmark's request shapes (bench.py `_workloads`). A repeated
+    """The BASELINE.json request shapes. A repeated
     query is a leaf-cache hit and never reaches the device, so each warm
     variant changes what the plan carries as traced inputs."""
     error = [("severity_text", "ERROR")]

@@ -7,7 +7,7 @@ storage, with decoupled stateless indexers and searchers.
 
 Unlike the Rust/tantivy reference, the leaf-search hot path — term/range
 filtering, BM25 scoring, top-K collection, and columnar aggregations — runs
-as JAX/XLA (and Pallas) kernels over HBM-resident dense arrays, and the
+as JAX/XLA kernels over HBM-resident dense arrays, and the
 scatter-gather merge tree is a sharded top-K + aggregation reduce over a
 `jax.sharding.Mesh` (ICI collectives) instead of per-node gRPC fan-in.
 
@@ -21,7 +21,7 @@ Package layout (mirrors the reference's layer map, SURVEY.md §1):
 - ``storage``       object-storage abstraction + caches (quickwit-storage)
 - ``index``         TPU-first split format: blocked postings, columns,
                     doc store, hotcache (quickwit-directories + tantivy fmt)
-- ``ops``           JAX/Pallas kernels: masks, BM25, top-K, aggregations
+- ``ops``           JAX kernels: masks, BM25, top-K, aggregations
 - ``search``        leaf/root search, collectors, caches (quickwit-search)
 - ``parallel``      mesh fan-out + ICI merge tree (the pmap'd merge of
                     BASELINE.json)

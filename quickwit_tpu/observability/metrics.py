@@ -424,8 +424,8 @@ PREDICATE_STAGED_BYTES_TOTAL = METRICS.counter(
 SEARCH_KERNEL_LAUNCHES_TOTAL = METRICS.counter(
     "qw_search_kernel_launches_total",
     "Device program launches of the served path: solo, multi-query, stacked, "
-    "mask-fill (search/executor.py) and the fused batch and query-group "
-    "families (parallel/fanout.py)")
+    "mask-fill (search/executor.py) and the mesh batch family "
+    "(parallel/fanout.py)")
 # One observation a leaf group of more than one split that ran per split:
 # how many of its splits' programs were launched together
 # (search/service.py::_execute_per_split). A group of one is not observed.

@@ -293,54 +293,6 @@ def merge_topk_chunks(chunks, k: int):
     return out_vals, out_vals2, out_ids, out_scores
 
 
-def batched_topk(x: jnp.ndarray, k: int):
-    """Per-query exact top-k over a stacked [Q, N] key matrix: the query
-    axis of a stacked multi-query dispatch (search/batcher.py). vmap over
-    `exact_topk` so each lane runs the SAME blockwise two-stage it would
-    run solo — per-query tie-breaks (lowest index wins on equal keys) are
-    bit-identical to solo execution by construction, which is what lets a
-    stacked group's readback splice against solo baselines. Returns
-    `(vals[Q, k], idx[Q, k])`."""
-    import jax
-    return jax.vmap(lambda row: exact_topk(row, k))(x)
-
-
-def batched_topk_2key(key1: jnp.ndarray, key2: jnp.ndarray, k: int):
-    """Two-sort-field variant of `batched_topk` over stacked [Q, N] key
-    matrices. Returns `(key1_top[Q, k], key2_top[Q, k], idx[Q, k])`."""
-    import jax
-    return jax.vmap(lambda a, b: exact_topk_2key(a, b, k))(key1, key2)
-
-
-def segment_merge_by_query(values: jnp.ndarray, query_ids: jnp.ndarray,
-                           num_queries: int, op: str) -> jnp.ndarray:
-    """Mergeable-agg reduction segmented by query id.
-
-    A stacked dispatch's agg accumulators arrive flattened over
-    (query lane × shard/chunk): `values` is [Q*S] (or [Q*S, ...] with the
-    reduction over axis 0 per segment) and `query_ids` assigns each row to
-    its query lane. Segment reduction keeps the merge ONE device op for
-    the whole group instead of Q host-side merges — the query-axis
-    equivalent of the root's mergeable-agg tree. `op` is the agg's merge
-    combinator: "sum" (count/sum/avg numerators), "min", "max".
-
-    Bit-exactness: sum segments accumulate in ascending row order per
-    segment (jax segment_sum), matching the solo merge's left fold over
-    shards; min/max are order-free.
-    """
-    import jax
-    if op == "sum":
-        return jax.ops.segment_sum(values, query_ids,
-                                   num_segments=num_queries)
-    if op == "min":
-        return jax.ops.segment_min(values, query_ids,
-                                   num_segments=num_queries)
-    if op == "max":
-        return jax.ops.segment_max(values, query_ids,
-                                   num_segments=num_queries)
-    raise ValueError(f"unmergeable segment op: {op!r}")
-
-
 def exact_topk_2key(key1: jnp.ndarray, key2: jnp.ndarray, k: int):
     """Exact lexicographic top-k by (key1, key2) descending, index-ascending
     tie-break — the two-sort-field variant of `exact_topk`, built on

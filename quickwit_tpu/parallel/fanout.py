@@ -41,10 +41,13 @@ from ..index.format import ZONEMAP_BLOCK
 from ..index.reader import SplitReader
 from ..models.doc_mapper import DocMapper
 from ..observability import flight
-from ..observability.metrics import SEARCH_KERNEL_LAUNCHES_TOTAL
+from ..observability.metrics import (
+    MESH_COLLECTIVE_BYTES_TOTAL, MESH_DEVICES, MESH_DISPATCHES_TOTAL,
+    MESH_THRESHOLD_EXCHANGE_ROUNDS_TOTAL, SEARCH_KERNEL_LAUNCHES_TOTAL,
+)
 from ..observability.profile import (
     PHASE_COMPILE, PHASE_EXECUTE, PHASE_PLAN_BUILD, PHASE_STAGING_CACHE_HIT,
-    PHASE_STAGING_UPLOAD, PHASE_TOPK_MERGE, SCOPE_PACK, SCOPE_TOPK,
+    PHASE_STAGING_UPLOAD, PHASE_TOPK_MERGE, SCOPE_PACK,
     current_profile, profile_add, profiled_phase,
 )
 from ..query.aggregations import DateHistogramAgg, HistogramAgg, TermsAgg, parse_aggs
@@ -319,26 +322,6 @@ def _agg_leaf_kind(path) -> Optional[str]:
     return None
 
 
-def _merge_agg_stack(agg_out):
-    """agg_out leaves carry a leading split axis [n, ...] → reduce axis 0
-    (counts/sums add, min/max combine by leaf name)."""
-    def red(path, leaf):
-        name = _agg_leaf_kind(path)
-        if name == "min":
-            return jnp.min(leaf, axis=0)
-        if name in ("max", "hll"):  # HLL registers merge by max too
-            return jnp.max(leaf, axis=0)
-        if name == "stats":
-            # state vector [count, sum, sum_sq, min, max]: first three add
-            return jnp.concatenate([
-                jnp.sum(leaf[:, :3], axis=0),
-                jnp.min(leaf[:, 3:4], axis=0),
-                jnp.max(leaf[:, 4:5], axis=0),
-            ])
-        return jnp.sum(leaf, axis=0)
-    return jax.tree_util.tree_map_with_path(red, agg_out)
-
-
 def batch_shardings(batch: SplitBatch, mesh: Mesh):
     """NamedShardings for the stacked inputs: every slot is sharded over the
     'splits' axis; dense per-doc slots (columns, fieldnorms) additionally
@@ -360,48 +343,6 @@ def batch_shardings(batch: SplitBatch, mesh: Mesh):
     return tuple(array_shardings), tuple(scalar_shardings), nd_sharding
 
 
-def batch_fn(batch: SplitBatch, k: int, exact: bool = False):
-    """The unjitted merged-batch closure (arrays, scalars, num_docs) →
-    result tree — exposed so measurement harnesses can wrap it (e.g. in a
-    device-side repeat loop) before jitting."""
-    template = batch.template
-    single_fn = executor_mod._build(template, k, exact)
-
-    def fn(arrays, scalars, num_docs):
-        results = jax.vmap(single_fn)(arrays, scalars, num_docs)
-        sort_vals, sort_vals2, doc_ids, hit_scores, counts, topk_safe, \
-            agg_out = results
-        total = jnp.sum(counts)
-        # one certificate for the whole batch: any unsafe split taints the
-        # cross-split merge, so the host re-runs the batch exactly
-        safe = jnp.min(topk_safe)
-        if k == 0:  # count/agg-only: no cross-split hit merge
-            empty_i = jnp.zeros((0,), jnp.int32)
-            return (jnp.zeros((0,), sort_vals.dtype), None, empty_i, empty_i,
-                    jnp.zeros((0,), hit_scores.dtype), total, safe,
-                    _merge_agg_stack(agg_out))
-        # flatten [n, k] → [n*k]; split-major order keeps the
-        # (key desc, split asc, doc asc) tie-break of the collector
-        with jax.named_scope(SCOPE_TOPK):   # the cross-split merge
-            if sort_vals2 is None:
-                top_vals, pos = jax.lax.top_k(sort_vals.reshape(-1), k)
-                top_vals2 = None
-            else:
-                # 2-key sorts: lexicographic cross-split re-top-k (the
-                # same kernel the per-split path uses, over the flattened
-                # winners)
-                from ..ops import topk as topk_ops
-                top_vals, top_vals2, pos = topk_ops.exact_topk_2key(
-                    sort_vals.reshape(-1), sort_vals2.reshape(-1), k)
-            split_idx = (pos // k).astype(jnp.int32)
-            flat_ids = doc_ids.reshape(-1)[pos]
-            flat_scores = hit_scores.reshape(-1)[pos]
-        return top_vals, top_vals2, split_idx, flat_ids, flat_scores, \
-            total, safe, _merge_agg_stack(agg_out)
-
-    return fn
-
-
 def _mesh_axes(mesh: Mesh) -> tuple[str, Optional[str]]:
     """(split_axis_name, doc_axis_name) of a fanout mesh. Axis names come
     from the mesh itself (not hard-coded literals) so qwir's R4 planted-
@@ -411,16 +352,17 @@ def _mesh_axes(mesh: Mesh) -> tuple[str, Optional[str]]:
     return names[0], (names[1] if len(names) > 1 else None)
 
 
-def _usable_mesh(batch: SplitBatch, mesh: Optional[Mesh]) -> Optional[Mesh]:
-    """A mesh the batch can actually shard over, else None (single-device
-    host-merge degenerate). NamedSharding refuses ragged dimension-0
-    shards outright, so a split axis that does not divide the batch has
-    no partial fallback — the service's `device_mesh` only hands out
-    dividing axes; this guards direct `execute_batch`/staging callers."""
-    if mesh is None:
-        return None
+def _check_mesh_divides(batch: SplitBatch, mesh: Mesh) -> None:
+    """NamedSharding refuses ragged dimension-0 shards, so a split axis
+    that does not divide the batch has no program to run. The service's
+    `device_mesh` only hands out dividing axes; this guards direct
+    `dispatch_batch` / `stage_device_inputs` callers."""
     split_ax, _doc_ax = _mesh_axes(mesh)
-    return mesh if batch.n_splits % mesh.shape[split_ax] == 0 else None
+    axis_splits = mesh.shape[split_ax]
+    if batch.n_splits % axis_splits:
+        raise ValueError(
+            f"n_splits={batch.n_splits} does not shard over the "
+            f"{axis_splits}-way {split_ax!r} mesh axis (pad the batch)")
 
 
 def _all_reduce_extremum(x, axis_name: str, op: str):
@@ -439,9 +381,10 @@ def _all_reduce_extremum(x, axis_name: str, op: str):
 
 
 def _merge_agg_collective(agg_out, split_ax: str):
-    """`_merge_agg_stack`'s collective twin: the local [local_n, ...] stack
-    reduces over axis 0 on each device, then the SAME per-leaf combiner
-    runs once more across the split mesh axis (psum /
+    """Merge the per-split agg states: leaves carry a leading split axis
+    [local_n, ...] that reduces over axis 0 on each device (counts and
+    sums add, min/max/hll combine by leaf name), then the SAME per-leaf
+    combiner runs once more across the split mesh axis (psum /
     `_all_reduce_extremum`), so the merged states land replicated on every
     device — no host merge.
 
@@ -492,9 +435,8 @@ def mesh_batch_fn(batch: SplitBatch, k: int, mesh: Mesh, exact: bool = False):
          axis — device order equals split order under the P("splits")
          input sharding, so the concatenation is split-major and
          `lax.top_k`'s lowest-index tie-break reproduces the collector's
-         (key desc, split_id asc, doc asc) total order bit-for-bit, the
-         same argument as the host `batch_fn` merge (2-key sorts ride
-         `exact_topk_2key` over the gathered pairs).
+         (key desc, split_id asc, doc asc) total order bit-for-bit
+         (2-key sorts ride `exact_topk_2key` over the gathered pairs).
       3. agg + count reduce: mergeable agg states, hit counts, and the
          guided-top-k certificate reduce via psum and
          `_all_reduce_extremum`.
@@ -508,20 +450,16 @@ def mesh_batch_fn(batch: SplitBatch, k: int, mesh: Mesh, exact: bool = False):
 
     template = batch.template
     single_fn = executor_mod._build(template, k, exact)
+    _check_mesh_divides(batch, mesh)
     split_ax, _doc_ax = _mesh_axes(mesh)
-    axis_splits = mesh.shape[split_ax]
-    if batch.n_splits % axis_splits:
-        raise ValueError(
-            f"n_splits={batch.n_splits} does not shard over the "
-            f"{axis_splits}-way {split_ax!r} mesh axis (pad the batch)")
 
     def shard_body(arrays, scalars, num_docs):
         results = jax.vmap(single_fn)(arrays, scalars, num_docs)
         sort_vals, sort_vals2, doc_ids, hit_scores, counts, topk_safe, \
             agg_out = results
         total = lax.psum(jnp.sum(counts), split_ax)
-        # one certificate for the whole batch (see batch_fn): the
-        # cross-device jnp.min
+        # one certificate for the whole batch: any unsafe split taints the
+        # cross-split merge, so the host re-runs the batch exactly
         safe = _all_reduce_extremum(jnp.min(topk_safe), split_ax, "min")
         merged = _merge_agg_collective(agg_out, split_ax)
         if k == 0:  # count/agg-only: no candidates to exchange or gather
@@ -561,7 +499,7 @@ def mesh_batch_fn(batch: SplitBatch, k: int, mesh: Mesh, exact: bool = False):
                          out_specs=P(), check_vma=False)
 
 
-def batch_cache_key(batch: SplitBatch, k: int, mesh: Optional[Mesh],
+def batch_cache_key(batch: SplitBatch, k: int, mesh: Mesh,
                     exact: bool = False) -> tuple:
     """The `_BATCH_JIT_CACHE` key `dispatch_batch` uses, post k-clamp —
     mirrored here for tools/qwir's compile-cache closure certificate (must
@@ -571,33 +509,14 @@ def batch_cache_key(batch: SplitBatch, k: int, mesh: Optional[Mesh],
             batch.num_docs_padded, mesh, exact)
 
 
-def abstract_batch_program(batch: SplitBatch, k: int, exact: bool = False):
-    """ClosedJaxpr of the fused merged-batch program (`batch_fn`'s closure,
-    minus the packed f64 readback concat) — abstract-traced over
-    ShapeDtypeStructs, never compiled or executed, no mesh required.
-
-    The mesh dispatch path no longer relies on GSPMD inference — it jits
-    the explicitly-collective `mesh_batch_fn`; use
-    `abstract_mesh_batch_program` to audit that one."""
-    k = min(max(0, k), batch.num_docs_padded)
-    fn = batch_fn(batch, k, exact)
-    arrays = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
-                   for a in batch.arrays)
-    scalars = tuple(jax.ShapeDtypeStruct(s.shape, s.dtype)
-                    for s in batch.scalars)
-    nd = jax.ShapeDtypeStruct(batch.num_docs.shape, batch.num_docs.dtype)
-    return jax.make_jaxpr(fn)(arrays, scalars, nd)
-
-
 def abstract_mesh_batch_program(batch: SplitBatch, k: int, mesh: Mesh,
                                 exact: bool = False):
     """ClosedJaxpr of the collective whole-query program (`mesh_batch_fn`,
     minus the packed f64 readback concat) — abstract-traced, never
-    compiled or executed. Unlike `abstract_batch_program`, the collectives
-    here are EXPLICIT eqns (shard_map + psum/all_gather, and pmax/pmin on
-    32-bit leaves), which is what makes qwir R4's mesh-axis rule
-    load-bearing: every collective must bind axes declared by the
-    program's ProgramSpec."""
+    compiled or executed. The collectives are EXPLICIT eqns (shard_map +
+    psum/all_gather, and pmax/pmin on 32-bit leaves), which is what makes
+    qwir R4's mesh-axis rule load-bearing: every collective must bind axes
+    declared by the program's ProgramSpec."""
     k = min(max(0, k), batch.num_docs_padded)
     fn = mesh_batch_fn(batch, k, mesh, exact)
     arrays = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
@@ -613,34 +532,14 @@ def abstract_mesh_batch_program(batch: SplitBatch, k: int, mesh: Mesh,
 # sort over n_splits*k lanes, O(fan-out × page size), NOT corpus-scale.
 # The corpus-scale sorts it consumes already ran under the certified
 # ops/topk.py kernels inside the vmapped per-split programs.
-_MESH_MERGE_F64 = (
-    "the on-mesh root merge: the same cross-split re-top-k as batch_fn "
-    "over the all_gather'd [n_splits*k] threshold-surviving winners, plus "
-    "the k-element threshold exchange sort — bounded by fan-out times page "
-    "size.")
 # keyed by qualname, as the jaxpr eqn source frames report it
 QWIR_CERTIFIED_F64 = {
-    "batch_fn.<locals>.fn": (
-        "batch_fn's cross-split merge: lax.top_k / exact_topk_2key over "
-        "the flattened [n_splits*k] per-split winners — bounded by fan-out "
+    "mesh_batch_fn.<locals>.shard_body": (
+        "mesh_batch_fn's on-mesh root merge: lax.top_k / exact_topk_2key "
+        "over the all_gather'd [n_splits*k] threshold-surviving winners, "
+        "plus the k-element threshold exchange sort — bounded by fan-out "
         "times page size, never by corpus size."),
-    "mesh_batch_fn.<locals>.shard_body": "mesh_batch_fn's " + _MESH_MERGE_F64,
-    "group_mesh_fn.<locals>.shard_body": (
-        "group_mesh_fn's, per query lane, " + _MESH_MERGE_F64),
 }
-
-
-def _donate_batch_inputs(mesh: Optional[Mesh] = None) -> bool:
-    """Donate the stacked batch arrays to the executor so XLA reuses their
-    HBM as scratch: the stacks are per-request copies of the column data
-    (the resident per-split arrays are NOT what is donated) and are
-    invalidated after the dispatch that consumed them. CPU PJRT does not
-    implement donation and warns per compile, so gate on backend. Mesh
-    dispatches never donate: their staged tuples may alias mesh-resident
-    column stacks (`_stage_resident_stack`) that must survive the query —
-    and the decision is baked into the cached jit, which is keyed only on
-    (signature, n_splits, padded, mesh, exact), not on residency."""
-    return mesh is None and jax.default_backend() != "cpu"
 
 
 def _collective_payload_bytes(shaped, k: int, n_splits: int,
@@ -677,29 +576,21 @@ def _collective_payload_bytes(shaped, k: int, n_splits: int,
     return gather + reduced + exchange
 
 
-def _batch_executor(batch: SplitBatch, k: int, mesh: Optional[Mesh],
+def _batch_executor(batch: SplitBatch, k: int, mesh: Mesh,
                     example_args, exact: bool = False):
-    """(jitted_packed_fn, treedef, spec, meta): the merged result tree
-    rides ONE f64 device array so the readback is a single transfer (see
-    executor.py packed-readback rationale; exactness argument identical).
-
-    With a mesh, the jitted program is the explicitly-collective
-    `mesh_batch_fn` (the whole root merge on-device); without one it is
-    the host-degenerate `batch_fn`. Callers never reach here with a mesh
-    whose split axis does not divide the batch: `_usable_mesh` drops such
-    meshes to the single-device path at dispatch time (NamedSharding
-    rejects ragged dimension-0 shards at staging, so there is no partial
-    fallback to salvage)."""
-    collective = mesh is not None
-    fn = (mesh_batch_fn(batch, k, mesh, exact) if collective
-          else batch_fn(batch, k, exact))
+    """(jitted_packed_fn, treedef, spec, meta): the explicitly-collective
+    `mesh_batch_fn` (the whole root merge on-device), its merged result
+    tree riding ONE f64 device array so the readback is a single transfer
+    (see executor.py packed-readback rationale; exactness argument
+    identical). Never donates: the staged tuples may alias mesh-resident
+    column stacks (`_stage_resident_stack`) that must survive the query."""
+    fn = mesh_batch_fn(batch, k, mesh, exact)
     shaped = jax.eval_shape(fn, *example_args)
     treedef = jax.tree_util.tree_structure(shaped)
     spec = [(leaf.shape, leaf.dtype)
             for leaf in jax.tree_util.tree_leaves(shaped)]
     meta = {"collective_bytes": _collective_payload_bytes(
-        shaped, k, batch.n_splits, mesh.shape[_mesh_axes(mesh)[0]])} \
-        if collective else None
+        shaped, k, batch.n_splits, mesh.shape[_mesh_axes(mesh)[0]])}
 
     def packed(arrays, scalars, num_docs):
         out = fn(arrays, scalars, num_docs)
@@ -709,14 +600,10 @@ def _batch_executor(batch: SplitBatch, k: int, mesh: Optional[Mesh],
             return jnp.concatenate(flat) if flat else jnp.zeros((0,))
 
     # static program name from the cache key alone: family, lanes, k, mesh
-    executor_mod._named(packed, f"qw_batch_s{batch.n_splits}_k{k}" + (
-        f"_mesh{mesh.size}" if collective else ""))
-    donate = (0,) if _donate_batch_inputs(mesh) else ()
-    if mesh is None:
-        return jax.jit(packed, donate_argnums=donate), treedef, spec, meta
+    executor_mod._named(
+        packed, f"qw_batch_s{batch.n_splits}_k{k}_mesh{mesh.size}")
     arrays_sh, scalars_sh, nd_sh = batch_shardings(batch, mesh)
-    return (jax.jit(packed, in_shardings=(arrays_sh, scalars_sh, nd_sh),
-                    donate_argnums=donate),
+    return (jax.jit(packed, in_shardings=(arrays_sh, scalars_sh, nd_sh)),
             treedef, spec, meta)
 
 
@@ -736,21 +623,18 @@ def stack_resident_slots(batch: SplitBatch) -> list[int]:
             if key.startswith(_STACK_RESIDENT_PREFIXES)]
 
 
-def per_device_bytes(batch: SplitBatch, mesh: Optional[Mesh],
+def per_device_bytes(batch: SplitBatch, mesh: Mesh,
                      exclude_stack_resident: bool = False) -> int:
     """The PER-DEVICE HBM footprint of the staged batch — what tenant-DRR
-    admission should pin when the stacks shard over a mesh. Dense column
-    slots divide across both axes (P("splits", "docs")); everything else
-    divides across the split axis only (`batch_shardings`). Without a
-    mesh this is the full single-device byte count the seed admitted.
+    admission pins: the stacks shard over the mesh. Dense column slots
+    divide across both axes (P("splits", "docs")); everything else
+    divides across the split axis only (`batch_shardings`).
 
     `exclude_stack_resident` drops the column-family slots: when the
     mesh-resident stack store is active those bytes are admitted under
     the stack owner by `stage_device_inputs` (and stay resident after the
     query), so admitting them under the per-request batch owner too would
     double-pin warm queries."""
-    if mesh is None:
-        return sum(a.nbytes for a in batch.arrays)
     split_ax, doc_ax = _mesh_axes(mesh)
     n_sp = mesh.shape[split_ax]
     n_doc = mesh.shape.get(doc_ax, 1) if doc_ax else 1
@@ -835,7 +719,7 @@ def _stage_resident_stack(batch: SplitBatch, mesh: Mesh, arrays_sh,
         raise
 
 
-def stage_device_inputs(batch: SplitBatch, mesh: Optional[Mesh] = None,
+def stage_device_inputs(batch: SplitBatch, mesh: Mesh,
                         resident_store=None, budget=None):
     """Start the batch's host→device transfer (async under JAX dispatch)
     and cache the device arrays on the batch for repeat queries — keyed by
@@ -843,11 +727,11 @@ def stage_device_inputs(batch: SplitBatch, mesh: Optional[Mesh] = None,
     compiled for another. Callable from a prefetch thread so the transfer
     overlaps the previous batch's kernel execution.
 
-    With a mesh and a resident store, column-family slots are served from
-    the cross-query mesh stack (`_stage_resident_stack`): only the
+    With a resident store, column-family slots are served from the
+    cross-query mesh stack (`_stage_resident_stack`): only the
     query-shaped slots (postings, scalars, doc counts) ride this request's
     upload."""
-    mesh = _usable_mesh(batch, mesh)
+    _check_mesh_divides(batch, mesh)
     cache = getattr(batch, "_device_inputs", None)
     if cache is None:
         cache = batch._device_inputs = {}
@@ -862,47 +746,36 @@ def stage_device_inputs(batch: SplitBatch, mesh: Optional[Mesh] = None,
                 rec["stage"] = "batch"
         flight.emit("staging.resident_hit", attrs={"stage": "batch"})
         return dev
-    if dev is None:
-        arrays_sh = scalars_sh = nd_sh = None
-        if mesh is not None:
-            arrays_sh, scalars_sh, nd_sh = batch_shardings(batch, mesh)
-        resident: dict[int, Any] = {}
-        if (mesh is not None and resident_store is not None
-                and getattr(resident_store, "enabled", False)):
-            resident = _stage_resident_stack(batch, mesh, arrays_sh,
-                                             resident_store, budget)
-        staging_bytes = (sum(a.nbytes for slot, a in enumerate(batch.arrays)
-                             if slot not in resident)
-                         + sum(s.nbytes for s in batch.scalars)
-                         + batch.num_docs.nbytes)
-        # staging times the transfer DISPATCH (device_put is async;
-        # completion overlaps into the execute phase by design — same
-        # contract as the per-split warmup in search/leaf.py)
-        with profiled_phase(PHASE_STAGING_UPLOAD) as rec:
-            if rec is not None:
-                rec["bytes"] = staging_bytes
-                rec["stage"] = "batch"
-            if mesh is not None:
-                arrays = tuple(
-                    resident[slot] if slot in resident
-                    else jax.device_put(a, arrays_sh[slot])
-                    for slot, a in enumerate(batch.arrays))
-                scalars = tuple(jax.device_put(batch.scalars,
-                                               list(scalars_sh))) \
-                    if batch.scalars else ()
-                nd = jax.device_put(batch.num_docs, nd_sh)
-            else:
-                moved = jax.device_put(
-                    batch.arrays + batch.scalars + [batch.num_docs])
-                arrays = tuple(moved[: len(batch.arrays)])
-                scalars = tuple(moved[len(batch.arrays):-1])
-                nd = moved[-1]
-        profile_add("staging_bytes", staging_bytes)
-        if flight.recording():
-            flight.emit("staging.upload",
-                        attrs={"bytes": staging_bytes,
-                               "resident_slots": len(resident)})
-        dev = cache[mesh] = (arrays, scalars, nd)
+    arrays_sh, scalars_sh, nd_sh = batch_shardings(batch, mesh)
+    resident: dict[int, Any] = {}
+    if resident_store is not None \
+            and getattr(resident_store, "enabled", False):
+        resident = _stage_resident_stack(batch, mesh, arrays_sh,
+                                         resident_store, budget)
+    staging_bytes = (sum(a.nbytes for slot, a in enumerate(batch.arrays)
+                         if slot not in resident)
+                     + sum(s.nbytes for s in batch.scalars)
+                     + batch.num_docs.nbytes)
+    # staging times the transfer DISPATCH (device_put is async;
+    # completion overlaps into the execute phase by design — same
+    # contract as the per-split warmup in search/leaf.py)
+    with profiled_phase(PHASE_STAGING_UPLOAD) as rec:
+        if rec is not None:
+            rec["bytes"] = staging_bytes
+            rec["stage"] = "batch"
+        arrays = tuple(
+            resident[slot] if slot in resident
+            else jax.device_put(a, arrays_sh[slot])
+            for slot, a in enumerate(batch.arrays))
+        scalars = tuple(jax.device_put(batch.scalars, list(scalars_sh))) \
+            if batch.scalars else ()
+        nd = jax.device_put(batch.num_docs, nd_sh)
+    profile_add("staging_bytes", staging_bytes)
+    if flight.recording():
+        flight.emit("staging.upload",
+                    attrs={"bytes": staging_bytes,
+                           "resident_slots": len(resident)})
+    dev = cache[mesh] = (arrays, scalars, nd)
     return dev
 
 
@@ -922,8 +795,7 @@ def stage_device_inputs(batch: SplitBatch, mesh: Optional[Mesh] = None,
 # device_get, or `abandon_dispatch` on the deadline-shed path) — the
 # blocking wait itself runs OUTSIDE any lexical lock scope, so waiters
 # queue on the guard, not on a device round-trip hidden inside a `with`
-# block. Single-device dispatches (mesh is None) carry no collectives and
-# take no lock.
+# block.
 # qwlint: disable-next-line=QW008 - leaf lock by design: the critical
 # section is a jax enqueue (hardware) or enqueue→completion (CPU host
 # platform), never a seam primitive, so the gated qwrace scheduler cannot
@@ -932,15 +804,13 @@ def stage_device_inputs(batch: SplitBatch, mesh: Optional[Mesh] = None,
 _MESH_DISPATCH_LOCK = threading.Lock()
 
 
-def _enqueue_batch(ex, arrays, scalars, nd, mesh):
+def _enqueue_batch(ex, arrays, scalars, nd):
     """Enqueue one batch program; returns (out, guard). `guard` is the
     still-held `_MESH_DISPATCH_LOCK` on the CPU host platform (the caller
     MUST hand it to `_finish_mesh_dispatch` once the program has been
-    awaited), None otherwise. Every launch of the fused batch and
-    query-group families passes here, so here it is counted."""
+    awaited), None otherwise. Every launch of the mesh batch family
+    passes here, so here it is counted."""
     SEARCH_KERNEL_LAUNCHES_TOTAL.inc()
-    if mesh is None:
-        return ex(arrays, scalars, nd), None
     _MESH_DISPATCH_LOCK.acquire()
     try:
         out = ex(arrays, scalars, nd)
@@ -976,18 +846,19 @@ def abandon_dispatch(dispatched) -> None:
 
 
 def dispatch_batch(batch: SplitBatch, request: SearchRequest,
-                   mesh: Optional[Mesh] = None, exact: bool = False):
-    """Async half of the fused batch dispatch: stage (or reuse) the device
-    inputs, enqueue ONE XLA program over all splits, start the D2H copy of
-    the packed result, and return without blocking. `readback_batch`
-    completes it — the seam lets the service shed deadline-expired queries
-    before ever paying the readback wait, and overlap the next group's
-    dispatch with this one's readback."""
+                   mesh: Mesh, exact: bool = False):
+    """Async half of the mesh batch dispatch: stage (or reuse) the device
+    inputs, enqueue ONE collective XLA program over all splits, start the
+    D2H copy of the packed result, and return without blocking.
+    `readback_batch` completes it — the seam lets the service shed
+    deadline-expired queries before ever paying the readback wait, and
+    overlap the next group's dispatch with this one's readback. Raises
+    ValueError when the mesh's split axis does not divide the batch."""
     # cancelled queries stop HERE, before staging device inputs or paying
     # an enqueue nobody will read (the readback seam checks again)
     from ..common.deadline import check_cancelled
     check_cancelled("batch dispatch")
-    mesh = _usable_mesh(batch, mesh)
+    _check_mesh_divides(batch, mesh)
     # k=0 (count/agg-only): per-split executors skip keying/top-k and the
     # batch merge skips the cross-split top_k
     k = min(request.start_offset + request.max_hits, batch.num_docs_padded)
@@ -1007,14 +878,14 @@ def dispatch_batch(batch: SplitBatch, request: SearchRequest,
                     attrs={"path": "batch"})
         flight.emit("dispatch.launch",
                     attrs={"path": "batch", "splits": batch.n_splits,
-                           "mesh": mesh.size if mesh is not None else 0})
+                           "mesh": mesh.size})
     if profile is None:
         if cached is None:
             cached = _batch_executor(batch, k, mesh, (arrays, scalars, nd),
                                      exact)
             _BATCH_JIT_CACHE[key] = cached
         ex, treedef, spec, meta = cached
-        out, guard = _enqueue_batch(ex, arrays, scalars, nd, mesh)
+        out, guard = _enqueue_batch(ex, arrays, scalars, nd)
     else:
         # Compile-vs-execute attribution (same lazy-jit approximation as
         # executor.dispatch_plan): on a batch-jit-cache MISS the first call
@@ -1029,29 +900,18 @@ def dispatch_batch(batch: SplitBatch, request: SearchRequest,
                                          (arrays, scalars, nd), exact)
                 _BATCH_JIT_CACHE[key] = cached
             ex, treedef, spec, meta = cached
-            out, guard = _enqueue_batch(ex, arrays, scalars, nd, mesh)
+            out, guard = _enqueue_batch(ex, arrays, scalars, nd)
     try:
-        if meta is not None:
-            from ..observability.metrics import (
-                MESH_COLLECTIVE_BYTES_TOTAL, MESH_DEVICES,
-                MESH_DISPATCHES_TOTAL, MESH_THRESHOLD_EXCHANGE_ROUNDS_TOTAL,
-            )
-            MESH_DISPATCHES_TOTAL.inc()
-            MESH_DEVICES.set(mesh.size)
-            MESH_COLLECTIVE_BYTES_TOTAL.inc(meta["collective_bytes"])
-            if k > 0:
-                MESH_THRESHOLD_EXCHANGE_ROUNDS_TOTAL.inc()
-            if flight.recording():
-                flight.emit("mesh.collective",
-                            attrs={"devices": mesh.size,
-                                   "bytes": meta["collective_bytes"],
-                                   "threshold_exchange": int(k > 0)})
-        if _donate_batch_inputs(mesh):
-            # the stacked inputs were donated into this dispatch — drop the
-            # staging-cache entry so nothing touches the dead buffers
-            cache = getattr(batch, "_device_inputs", None)
-            if cache is not None:
-                cache.pop(mesh, None)
+        MESH_DISPATCHES_TOTAL.inc()
+        MESH_DEVICES.set(mesh.size)
+        MESH_COLLECTIVE_BYTES_TOTAL.inc(meta["collective_bytes"])
+        if k > 0:
+            MESH_THRESHOLD_EXCHANGE_ROUNDS_TOTAL.inc()
+        if flight.recording():
+            flight.emit("mesh.collective",
+                        attrs={"devices": mesh.size,
+                               "bytes": meta["collective_bytes"],
+                               "threshold_exchange": int(k > 0)})
         if hasattr(out, "copy_to_host_async"):
             out.copy_to_host_async()
     except BaseException:
@@ -1061,7 +921,7 @@ def dispatch_batch(batch: SplitBatch, request: SearchRequest,
 
 
 def readback_batch(dispatched) -> LeafSearchResponse:
-    """Blocking half of the fused batch dispatch: await the packed scalar
+    """Blocking half of the mesh batch dispatch: await the packed scalar
     readback, unpack, host-decode the merged hits/aggs. A `safe == 0`
     guided-top-k certificate triggers one exact re-execution of the whole
     batch (see ops/topk.py:guided_topk)."""
@@ -1116,9 +976,7 @@ def _decode_merged(batch: SplitBatch, k: int, top_vals, top_vals2,
                    split_idx, doc_ids, scores, num_hits: int,
                    merged_aggs) -> LeafSearchResponse:
     """Host decode of one merged (cross-split) result into a
-    LeafSearchResponse — shared by the single-query batch readback and the
-    per-lane unpack of a stacked query-group readback (one lane's slice of
-    the [Q, ...] result is exactly one merged batch result)."""
+    LeafSearchResponse."""
     hits: list[PartialHit] = []
     sort_is_int = _sort_values_are_int(batch.doc_mapper, batch.sort_field)
     sort2_is_int = (_sort_values_are_int(batch.doc_mapper, batch.sort2_field)
@@ -1172,500 +1030,8 @@ def _decode_merged(batch: SplitBatch, k: int, top_vals, top_vals2,
     )
 
 
-def execute_batch(batch: SplitBatch, request: SearchRequest,
-                  mesh: Optional[Mesh] = None,
+def execute_batch(batch: SplitBatch, request: SearchRequest, mesh: Mesh,
                   exact: bool = False) -> LeafSearchResponse:
-    """Run the batch (optionally mesh-sharded) and emit one merged
-    LeafSearchResponse covering all splits."""
+    """Run the batch over the mesh and emit one merged LeafSearchResponse
+    covering all splits."""
     return readback_batch(dispatch_batch(batch, request, mesh, exact))
-
-
-# --------------------------------------------------------------------------
-# query-axis × mesh composition (ROADMAP item 2 over item 6)
-#
-# N shape-compatible queries over the SAME split set execute as ONE mesh
-# program: a leading `queries` axis is vmapped INSIDE each device shard
-# (never a mesh axis — chips shard data, lanes share chips), operand slots
-# whose cache key agrees across the group broadcast once from the
-# mesh-resident column stack, query-shaped slots (postings, masks) gain a
-# [Q, n_splits, ...] leading dim sharded P(None, "splits"), and the on-mesh
-# root merge becomes per-query-lane collectives: the pmax threshold
-# exchange reduces a [Q] vector of per-lane k-th values, the all_gather
-# carries [Q, local_n*k] candidate tiles, and mergeable-agg states reduce
-# by query-id segments before the cross-device psum. A [Q] validity mask
-# rides as an operand, so a rider shed after group formation lane-zeroes
-# out of the packed readback without touching the compiled program.
-
-_GROUP_JIT_CACHE: dict[tuple, Any] = {}
-
-# Slot keys that may BROADCAST across query lanes: column families derive
-# only from the readers and the padded size, so equal keys over one split
-# set mean equal bytes (the same argument as the mesh-resident stack's
-# cache key). Posting/mask slots are query-shaped even when their keys
-# collide, so they always stack.
-_GROUP_SHARED_PREFIXES = ("col.", "norm.")
-
-
-def group_slot_split(batches: list) -> tuple[tuple[int, ...],
-                                             tuple[int, ...]]:
-    """(shared_slots, stacked_slots) for a query group: a slot broadcasts
-    when every lane carries the same array key AND the key is a
-    column-family key (content a pure function of the split set)."""
-    t0 = batches[0].template
-    shared, stacked = [], []
-    for slot, key in enumerate(t0.array_keys):
-        if key.startswith(_GROUP_SHARED_PREFIXES) and all(
-                b.template.array_keys[slot] == key for b in batches[1:]):
-            shared.append(slot)
-        else:
-            stacked.append(slot)
-    return tuple(shared), tuple(stacked)
-
-
-def _stack_group_operands(batches: list, stacked_slots) -> tuple:
-    """Host-side [Q, ...] stacking of the query-shaped operands. Stacked
-    slots pad their last dim to the group maximum (two terms' posting
-    lists rarely agree in length) using the SAME per-key pad fill the
-    split stacking uses, so pad lanes stay inert under every kernel."""
-    q = len(batches)
-    t0 = batches[0].template
-    stacked_arrays = []
-    for slot in stacked_slots:
-        per_q = [b.arrays[slot] for b in batches]
-        dtype = per_q[0].dtype
-        if any(a.dtype != dtype for a in per_q[1:]):
-            raise ValueError(
-                f"group slot {t0.array_keys[slot]!r} has non-uniform "
-                "dtypes across queries (incompatible column packings)")
-        max_len = max(a.shape[1] for a in per_q)
-        fill = _pad_fill(t0.array_keys[slot],
-                         batches[0].num_docs_padded, dtype)
-        out = np.full((q, per_q[0].shape[0], max_len), fill, dtype=dtype)
-        for i, a in enumerate(per_q):
-            out[i, :, : a.shape[1]] = a
-        stacked_arrays.append(out)
-    scalars_b = [np.stack([np.asarray(b.scalars[slot]) for b in batches])
-                 for slot in range(len(t0.scalars))]
-    return stacked_arrays, scalars_b
-
-
-def _assemble_group_slots(shared, lane_stacked, shared_slots,
-                          stacked_slots, num_slots) -> tuple:
-    slots: list = [None] * num_slots
-    for i, s in enumerate(shared_slots):
-        slots[s] = shared[i]
-    for i, s in enumerate(stacked_slots):
-        slots[s] = lane_stacked[i]
-    return tuple(slots)
-
-
-def _merge_agg_group_collective(agg_out, split_ax: str, q: int):
-    """`_merge_agg_collective`'s query-axis twin: leaves arrive
-    [Q, local_n, ...]; the local reduction runs as ONE query-id-segmented
-    device op over the flattened [Q*local_n, ...] rows
-    (ops/topk.segment_merge_by_query), then the per-leaf combiner crosses
-    the split mesh axis per lane (psum/`_all_reduce_extremum` act
-    elementwise over the leading [Q] dim). Exactness: segment_sum
-    accumulates rows in ascending index order within each segment — the
-    same left fold over local splits the single-query merge performs."""
-    from jax import lax
-
-    from ..ops import topk as topk_ops
-
-    def red(path, leaf):
-        name = _agg_leaf_kind(path)
-        local_n = leaf.shape[1]
-        flat = leaf.reshape((q * local_n,) + leaf.shape[2:])
-        qids = jnp.repeat(jnp.arange(q, dtype=jnp.int32), local_n)
-        if name == "min":
-            return _all_reduce_extremum(topk_ops.segment_merge_by_query(
-                flat, qids, q, "min"), split_ax, "min")
-        if name in ("max", "hll"):  # HLL registers merge by max too
-            return _all_reduce_extremum(topk_ops.segment_merge_by_query(
-                flat, qids, q, "max"), split_ax, "max")
-        if name == "stats":
-            # state vector [count, sum, sum_sq, min, max]: first three add
-            return jnp.concatenate([
-                lax.psum(topk_ops.segment_merge_by_query(
-                    flat[:, :3], qids, q, "sum"), split_ax),
-                _all_reduce_extremum(topk_ops.segment_merge_by_query(
-                    flat[:, 3:4], qids, q, "min"), split_ax, "min"),
-                _all_reduce_extremum(topk_ops.segment_merge_by_query(
-                    flat[:, 4:5], qids, q, "max"), split_ax, "max"),
-            ], axis=1)
-        # segment_sum keeps the operand dtype, but the solo merge's
-        # jnp.sum promotes integer accumulators (int32 counts → int64) —
-        # widen first so the stacked readback spec matches bit-for-bit
-        flat = flat.astype(jnp.zeros((), leaf.dtype).sum().dtype)
-        return lax.psum(topk_ops.segment_merge_by_query(
-            flat, qids, q, "sum"), split_ax)
-    return jax.tree_util.tree_map_with_path(red, agg_out)
-
-
-def group_fn(batches: list, k: int, exact: bool = False):
-    """Host-degenerate (no-mesh) stacked group closure: the query axis
-    vmaps the whole single-query merged-batch program (`batch_fn`), so
-    each lane runs bit-identically to its solo batch execution. Signature:
-    (shared_arrays, stacked_arrays, scalars_b, num_docs) → per-lane result
-    tree with leading [Q] dims."""
-    template = batches[0].template
-    shared_slots, stacked_slots = group_slot_split(batches)
-    num_slots = len(template.arrays)
-    base = batch_fn(batches[0], k, exact)
-
-    def fn(shared, stacked, scalars_b, num_docs):
-        def lane(lane_stacked, lane_scalars):
-            arrays = _assemble_group_slots(
-                shared, lane_stacked, shared_slots, stacked_slots,
-                num_slots)
-            return base(arrays, lane_scalars, num_docs)
-        return jax.vmap(lane)(tuple(stacked), tuple(scalars_b))
-
-    return fn
-
-
-def group_mesh_fn(batches: list, k: int, mesh: Mesh, exact: bool = False):
-    """The query group as ONE explicitly-collective SPMD program: the
-    stacked twin of `mesh_batch_fn` (same three merge steps, per query
-    lane — see that docstring for the exactness arguments; each reduces
-    elementwise over the leading [Q] dim, so lane q's merge consumes
-    exactly the operands its solo program would):
-
-      1. threshold exchange: [Q] per-lane k-th values, ONE
-         all-reduce-max round.
-      2. top-K merge: [Q, local_n*k] candidates all_gather along the
-         split axis (axis=1, tiled — split-major per lane), then a
-         batched top-k; 2-key sorts ride `ops/topk.batched_topk_2key`.
-      3. agg + count reduce: query-id-segmented local merges, then
-         per-lane psum/max/min (`_merge_agg_group_collective`).
-    """
-    from jax import lax
-
-    template = batches[0].template
-    q = len(batches)
-    shared_slots, stacked_slots = group_slot_split(batches)
-    num_slots = len(template.arrays)
-    single_fn = executor_mod._build(template, k, exact)
-    split_ax, _doc_ax = _mesh_axes(mesh)
-    axis_splits = mesh.shape[split_ax]
-    if batches[0].n_splits % axis_splits:
-        raise ValueError(
-            f"n_splits={batches[0].n_splits} does not shard over the "
-            f"{axis_splits}-way {split_ax!r} mesh axis (pad the batch)")
-
-    def shard_body(shared, stacked, scalars_b, num_docs):
-        def lane(lane_stacked, lane_scalars):
-            arrays = _assemble_group_slots(
-                shared, lane_stacked, shared_slots, stacked_slots,
-                num_slots)
-            return jax.vmap(single_fn)(arrays, lane_scalars, num_docs)
-        results = jax.vmap(lane)(tuple(stacked), tuple(scalars_b))
-        sort_vals, sort_vals2, doc_ids, hit_scores, counts, topk_safe, \
-            agg_out = results
-        total = lax.psum(jnp.sum(counts, axis=1), split_ax)        # [Q]
-        safe = _all_reduce_extremum(jnp.min(topk_safe, axis=1), split_ax,
-                                    "min")                         # [Q]
-        merged = _merge_agg_group_collective(agg_out, split_ax, q)
-        if k == 0:  # count/agg-only: no candidates to exchange or gather
-            empty_i = jnp.zeros((q, 0), jnp.int32)
-            return (jnp.zeros((q, 0), sort_vals.dtype), None, empty_i,
-                    empty_i, jnp.zeros((q, 0), hit_scores.dtype), total,
-                    safe, merged)
-        flat = sort_vals.reshape(q, -1)     # [Q, local_n*k], split-major
-        neg_inf = jnp.asarray(-jnp.inf, flat.dtype)
-        # -- threshold exchange: ONE round carries all Q lanes -----------
-        local_kth = lax.top_k(flat, k)[0][:, k - 1]
-        threshold = _all_reduce_extremum(local_kth, split_ax, "max")  # [Q]
-        keep = flat >= threshold[:, None]   # >= keeps threshold ties
-        flat = jnp.where(keep, flat, neg_inf)
-        # -- split-axis gather + per-lane re-top-k -----------------------
-        g_vals = lax.all_gather(flat, split_ax, axis=1, tiled=True)
-        g_ids = lax.all_gather(doc_ids.reshape(q, -1), split_ax,
-                               axis=1, tiled=True)
-        g_scores = lax.all_gather(hit_scores.reshape(q, -1), split_ax,
-                                  axis=1, tiled=True)
-        if sort_vals2 is None:
-            # lax.top_k is batched over leading dims: [Q, n*k] → [Q, k]
-            top_vals, pos = lax.top_k(g_vals, k)
-            top_vals2 = None
-        else:
-            flat2 = jnp.where(keep, sort_vals2.reshape(q, -1), neg_inf)
-            g_vals2 = lax.all_gather(flat2, split_ax, axis=1, tiled=True)
-            from ..ops import topk as topk_ops
-            top_vals, top_vals2, pos = topk_ops.batched_topk_2key(
-                g_vals, g_vals2, k)
-        split_idx = (pos // k).astype(jnp.int32)
-        return (top_vals, top_vals2, split_idx,
-                jnp.take_along_axis(g_ids, pos, axis=1),
-                jnp.take_along_axis(g_scores, pos, axis=1),
-                total, safe, merged)
-
-    in_shared = tuple(P(split_ax) for _ in shared_slots)
-    in_stacked = tuple(P(None, split_ax) for _ in stacked_slots)
-    in_scalars = tuple(P(None, split_ax) for _ in template.scalars)
-    return jax.shard_map(shard_body, mesh=mesh,
-                         in_specs=(in_shared, in_stacked, in_scalars,
-                                   P(split_ax)),
-                         out_specs=P(), check_vma=False)
-
-
-def group_cache_key(batches: list, k: int, mesh: Optional[Mesh] = None,
-                    exact: bool = False) -> tuple:
-    """The `_GROUP_JIT_CACHE` key `dispatch_query_group` uses, post
-    k-clamp — mirrored here for tools/qwir's compile-cache closure
-    certificate (must stay in lockstep with the key expression in
-    `dispatch_query_group`). The [Q] validity mask is an OPERAND, never
-    part of the key: shedding a rider does not recompile."""
-    b0 = batches[0]
-    k = min(k, b0.num_docs_padded)
-    _shared, stacked_slots = group_slot_split(batches)
-    return (b0.template.signature(k), len(batches), b0.n_splits,
-            b0.num_docs_padded, stacked_slots, mesh, exact)
-
-
-def _group_example_structs(batches: list, stacked_slots):
-    """ShapeDtypeStructs for (shared, stacked, scalars, num_docs) of the
-    group program — shared by the abstract qwir trace and eval_shape."""
-    b0 = batches[0]
-    shared_slots, _ = group_slot_split(batches)
-    stacked_arrays, scalars_b = _stack_group_operands(batches,
-                                                      stacked_slots)
-    shared = tuple(jax.ShapeDtypeStruct(b0.arrays[s].shape,
-                                        b0.arrays[s].dtype)
-                   for s in shared_slots)
-    stacked = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
-                    for a in stacked_arrays)
-    scalars = tuple(jax.ShapeDtypeStruct(s.shape, s.dtype)
-                    for s in scalars_b)
-    nd = jax.ShapeDtypeStruct(b0.num_docs.shape, b0.num_docs.dtype)
-    return shared, stacked, scalars, nd
-
-
-def abstract_group_mesh_program(batches: list, k: int, mesh: Mesh,
-                                exact: bool = False):
-    """ClosedJaxpr of the stacked query-group mesh program (`group_mesh_fn`,
-    minus the packed readback concat and validity mask) — abstract-traced,
-    never compiled or executed. The collectives are explicit eqns binding
-    the declared mesh axes, same as `abstract_mesh_batch_program`; the
-    query axis shows up as leading [Q] dims, NOT as a mesh axis."""
-    b0 = batches[0]
-    k = min(max(0, k), b0.num_docs_padded)
-    _shared_slots, stacked_slots = group_slot_split(batches)
-    fn = group_mesh_fn(batches, k, mesh, exact)
-    shared, stacked, scalars, nd = _group_example_structs(batches,
-                                                          stacked_slots)
-    return jax.make_jaxpr(fn)(shared, stacked, scalars, nd)
-
-
-def _group_executor(batches: list, k: int, mesh: Optional[Mesh],
-                    exact: bool = False):
-    """(jitted_packed_fn, treedef, spec): the group's result tree rides
-    ONE [Q, total] f64 device array — one transfer for all lanes — with
-    the [Q] validity mask zeroing shed lanes' rows (jnp.where, never
-    multiply: -inf × 0 is NaN)."""
-    q = len(batches)
-    _shared_slots, stacked_slots = group_slot_split(batches)
-    fn = (group_mesh_fn(batches, k, mesh, exact) if mesh is not None
-          else group_fn(batches, k, exact))
-    ex_shared, ex_stacked, ex_scalars, ex_nd = _group_example_structs(
-        batches, stacked_slots)
-    shaped = jax.eval_shape(fn, ex_shared, ex_stacked, ex_scalars, ex_nd)
-    treedef = jax.tree_util.tree_structure(shaped)
-    spec = [(leaf.shape, leaf.dtype)
-            for leaf in jax.tree_util.tree_leaves(shaped)]
-
-    def packed(shared, stacked, scalars_b, num_docs, valid):
-        out = fn(shared, stacked, scalars_b, num_docs)
-        with jax.named_scope(SCOPE_PACK):
-            flat = [leaf.reshape(q, -1).astype(jnp.float64)
-                    for leaf in jax.tree_util.tree_leaves(out)]
-            packed_rows = jnp.concatenate(flat, axis=1) if flat \
-                else jnp.zeros((q, 0))
-            return jnp.where(valid[:, None], packed_rows, 0.0)
-
-    executor_mod._named(
-        packed, f"qw_group_q{q}_s{batches[0].n_splits}_k{k}" + (
-            f"_mesh{mesh.size}" if mesh is not None else ""))
-    return jax.jit(packed), treedef, spec
-
-
-def dispatch_query_group(batches: list, request: SearchRequest,
-                         mesh: Optional[Mesh] = None, valid=None,
-                         exact: bool = False):
-    """Async half of a stacked query-group dispatch: N shape-compatible
-    queries (uniform template signature, same split set) enqueue as ONE
-    program. `valid` masks lanes shed after group formation; `None` means
-    all live. Returns the dispatched tuple for `readback_query_group`."""
-    from ..common.deadline import check_cancelled
-    check_cancelled("query-group dispatch")
-    b0 = batches[0]
-    q = len(batches)
-    sig0 = b0.template.signature(min(
-        request.start_offset + request.max_hits, b0.num_docs_padded))
-    for b in batches[1:]:
-        if b.split_ids != b0.split_ids:
-            raise ValueError("query group spans different split sets")
-    mesh = _usable_mesh(b0, mesh)
-    k = min(request.start_offset + request.max_hits, b0.num_docs_padded)
-    for b in batches[1:]:
-        if b.template.signature(k) != sig0:
-            raise ValueError(
-                "query group is not shape-compatible (template signatures "
-                "differ) — group by LoweredPlan.structure_digest upstream")
-    if valid is None:
-        valid = [True] * q
-    shared_slots, stacked_slots = group_slot_split(batches)
-    stacked_arrays, scalars_b = _stack_group_operands(batches,
-                                                      stacked_slots)
-    live = sum(1 for v in valid if v)
-    from ..observability.metrics import (
-        QBATCH_GROUPS_TOTAL, QBATCH_MASKED_RIDERS_TOTAL,
-        QBATCH_QUERIES_PER_DISPATCH, QBATCH_SHARED_BYTES_AVOIDED_TOTAL,
-    )
-    if live > 1:
-        QBATCH_GROUPS_TOTAL.inc()
-    QBATCH_QUERIES_PER_DISPATCH.observe(live)
-    if q - live:
-        QBATCH_MASKED_RIDERS_TOTAL.inc(q - live)
-    if live > 1 and shared_slots:
-        QBATCH_SHARED_BYTES_AVOIDED_TOTAL.inc(
-            sum(b0.arrays[s].nbytes for s in shared_slots) * (live - 1))
-    # staging: shared slots ride lane 0's staged batch inputs (and thus
-    # the mesh-resident column stack when one is active); stacked slots
-    # and scalars are per-group uploads
-    if mesh is not None:
-        arrays_sh, _scalars_sh, nd_sh = batch_shardings(b0, mesh)
-        from jax.sharding import NamedSharding
-        split_ax, _doc_ax = _mesh_axes(mesh)
-        shared_dev = tuple(jax.device_put(b0.arrays[s], arrays_sh[s])
-                           for s in shared_slots)
-        stacked_sh = NamedSharding(mesh, P(None, split_ax))
-        stacked_dev = tuple(jax.device_put(a, stacked_sh)
-                            for a in stacked_arrays)
-        scalars_dev = tuple(jax.device_put(s, stacked_sh)
-                            for s in scalars_b)
-        nd_dev = jax.device_put(b0.num_docs, nd_sh)
-    else:
-        moved = jax.device_put(
-            [b0.arrays[s] for s in shared_slots] + stacked_arrays
-            + scalars_b + [b0.num_docs])
-        shared_dev = tuple(moved[: len(shared_slots)])
-        stacked_dev = tuple(
-            moved[len(shared_slots): len(shared_slots) + len(stacked_arrays)])
-        scalars_dev = tuple(moved[len(shared_slots) + len(stacked_arrays):-1])
-        nd_dev = moved[-1]
-    valid_dev = jax.device_put(np.asarray(valid, dtype=bool))
-    # mirror: group_cache_key (qwir closure certificate lockstep)
-    key = (sig0, q, b0.n_splits, b0.num_docs_padded, stacked_slots, mesh,
-           exact)
-    cached = _GROUP_JIT_CACHE.get(key)
-    if flight.recording():
-        flight.emit("compile.hit" if cached is not None else "compile.miss",
-                    attrs={"path": "query_group"})
-        flight.emit("dispatch.launch",
-                    attrs={"path": "query_group", "lanes": q, "live": live,
-                           "mesh": mesh.size if mesh is not None else 0})
-    profile = current_profile()
-    if profile is not None:
-        profile.add("compile_cache_hits" if cached is not None
-                    else "compile_cache_misses")
-    ctx = profile.phase(PHASE_EXECUTE if cached is not None
-                        else PHASE_COMPILE, stage="dispatch_query_group") \
-        if profile is not None else None
-    try:
-        if ctx is not None:
-            ctx.__enter__()
-        if cached is None:
-            cached = _group_executor(batches, k, mesh, exact)
-            _GROUP_JIT_CACHE[key] = cached
-        ex, treedef, spec = cached
-        out, guard = _enqueue_batch(
-            lambda a, s, n: ex(shared_dev, stacked_dev, s, n, valid_dev),
-            None, scalars_dev, nd_dev, mesh)
-    finally:
-        if ctx is not None:
-            ctx.__exit__(None, None, None)
-    try:
-        if mesh is not None:
-            from ..observability.metrics import (
-                MESH_DEVICES, MESH_DISPATCHES_TOTAL,
-                MESH_THRESHOLD_EXCHANGE_ROUNDS_TOTAL,
-            )
-            MESH_DISPATCHES_TOTAL.inc()
-            MESH_DEVICES.set(mesh.size)
-            if k > 0:
-                # one round still carries ALL Q lanes' thresholds
-                MESH_THRESHOLD_EXCHANGE_ROUNDS_TOTAL.inc()
-            if flight.recording():
-                flight.emit("mesh.collective",
-                            attrs={"devices": mesh.size,
-                                   "path": "query_group",
-                                   "threshold_exchange": int(k > 0)})
-        if hasattr(out, "copy_to_host_async"):
-            out.copy_to_host_async()
-    except BaseException:
-        _finish_mesh_dispatch(guard, out)
-        raise
-    return out, treedef, spec, (list(batches), request, mesh, k,
-                                list(valid)), guard
-
-
-def readback_query_group(dispatched) -> list:
-    """Blocking half: ONE [Q, total] transfer, per-lane unpack + the same
-    merged-hit decode the single-query readback uses. Masked lanes return
-    None. A lane whose guided-top-k certificate reads unsafe re-runs as a
-    solo exact batch (per lane — an unsafe lane must not tax its
-    groupmates with a stacked re-dispatch)."""
-    out, treedef, spec, (batches, request, mesh, k, valid), guard = \
-        dispatched
-    from ..common.deadline import check_cancelled
-    t0 = _clock_monotonic() if flight.recording() else 0.0
-    try:
-        check_cancelled("query-group readback")
-        profile = current_profile()
-        if profile is None:
-            packed = jax.device_get(out)
-        else:
-            with profile.phase(PHASE_EXECUTE, stage="readback"):
-                packed = jax.device_get(out)
-    except BaseException:
-        _finish_mesh_dispatch(guard, out)
-        raise
-    _finish_mesh_dispatch(guard)
-    if flight.recording():
-        flight.emit("dispatch.readback", attrs={
-            "path": "query_group",
-            "dur_ms": round((_clock_monotonic() - t0) * 1000.0, 3)})
-    results: list = []
-    for lane, batch in enumerate(batches):
-        if not valid[lane]:
-            results.append(None)
-            continue
-        row = packed[lane]
-        leaves, offset = [], 0
-        for shape, dtype in spec:
-            lane_shape = shape[1:]
-            size = int(np.prod(lane_shape)) if lane_shape else 1
-            leaves.append(row[offset: offset + size]
-                          .astype(dtype).reshape(lane_shape))
-            offset += size
-        top_vals, top_vals2, split_idx, doc_ids, scores, total, safe, \
-            merged_aggs = jax.tree_util.tree_unflatten(treedef, leaves)
-        if float(safe) < 1.0:
-            executor_mod._note_guided_fallback()
-            results.append(execute_batch(batch, request, mesh, exact=True))
-            continue
-        results.append(_decode_merged(
-            batch, k, top_vals, top_vals2, split_idx, doc_ids, scores,
-            int(total), merged_aggs))
-    return results
-
-
-def execute_query_group(batches: list, request: SearchRequest,
-                        mesh: Optional[Mesh] = None,
-                        valid=None) -> list:
-    """Run N shape-compatible queries over one split set as ONE (optionally
-    mesh-collective) dispatch; returns one LeafSearchResponse per lane
-    (None for lanes masked by `valid`)."""
-    return readback_query_group(
-        dispatch_query_group(batches, request, mesh, valid=valid))

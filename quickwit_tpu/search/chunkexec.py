@@ -390,8 +390,9 @@ def chunk_spans(total: int, span: int, align: int) -> list[tuple[int, int]]:
 
 def _merge_agg_leaf(name: str, a, b):
     """One mergeable device output leaf — the SAME per-name rules as the
-    batch fan-out's cross-split `_merge_agg_stack` (parallel/fanout.py):
-    min/max/hll envelope, stats component-wise, everything else adds."""
+    mesh fan-out's cross-split reduction (`_merge_agg_collective`,
+    parallel/fanout.py): min/max/hll envelope, stats component-wise,
+    everything else adds."""
     if name == "min":
         return np.minimum(a, b)
     if name in ("max", "hll"):

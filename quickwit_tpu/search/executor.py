@@ -438,26 +438,6 @@ def _build_posting_space(plan: LoweredPlan, k: int,
             return (jnp.zeros((0,), jnp.float64), None,
                     jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.float32),
                     count, jnp.float64(1.0), tuple(agg_out))
-        from ..ops.pallas import fused_score_topk, pallas_available
-        if (sort.by == "score" and sort.by2 == "none" and root.scoring
-                and pallas_available() and k <= 64
-                and plan.threshold_slot < 0):
-            # QW_PALLAS=1: fused scoring + phase-1 top-k — the dense [P]
-            # scores array never materializes; hit scores come straight from
-            # the kernel's winners
-            vals_f32, pos = fused_score_topk(
-                ids, tfs, arrays[root.norm_slot][safe_ids],
-                scalars[root.idf_slot], scalars[root.avg_len_slot],
-                num_docs, k=min(k, num_postings),
-                interpret=jax.default_backend() == "cpu")
-            sort_vals = vals_f32.astype(jnp.float64)
-            doc_ids = ids[pos]
-            hit_scores = jnp.where(mask_ops.dead_lane_mask(vals_f32),
-                                   0.0, vals_f32)
-            gathered = _GatherView(arrays, safe_ids, scalars, plan.rebase)
-            agg_out = _eval_aggs(aggs, gathered, scalars, valid)
-            return sort_vals, None, doc_ids.astype(jnp.int32), hit_scores, \
-                count, jnp.float64(1.0), tuple(agg_out)
         if root.scoring:
             with jax.named_scope(SCOPE_BM25_SCORE):
                 scores = score_postings(

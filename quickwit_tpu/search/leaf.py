@@ -113,8 +113,8 @@ def warmup_device_arrays(reader: SplitReader, plan, budget=None,
     if missing:
         STAGING_BYTES_TOTAL.inc(staging_bytes)
         # predicate-only attribution: the bytes a mask-cache hit avoids.
-        # The bench's "zero predicate staging when warm" invariant asserts
-        # on exactly this counter (tools/bench.py::c11_dashboard_qps).
+        # tests/test_hierarchical_cache.py asserts "zero predicate staging
+        # when warm" on exactly this counter.
         pred_slots = predicate_only_slots(plan)
         predicate_bytes = sum(arr.nbytes for slot, _, arr in missing
                               if slot in pred_slots)

@@ -848,7 +848,7 @@ class SearchService:
                                         "segment_size"))):
             # Batch lanes must be in split_id order: the kernel's
             # cross-split merge breaks sort-value ties by flattened lane
-            # index (fanout.batch_fn / ops.topk.exact_topk_2key), and the
+            # index (fanout.mesh_batch_fn / ops.topk.exact_topk_2key), and the
             # collector's total order is (key desc, split_id asc, doc asc).
             # _optimize_split_order and the offload cut reorder/recompose
             # run_group between passes, so an all-ties search would

@@ -322,7 +322,7 @@ def test_text_field_sort_across_splits():
 
 def test_unsorted_tie_truncation_is_split_order_invariant(monkeypatch):
     """Regression: the batched cross-split merge breaks sort-value ties by
-    flattened lane index (parallel/fanout.py:batch_fn), so the batch lanes
+    flattened lane index (parallel/fanout.py:mesh_batch_fn), so the batch lanes
     must be pinned to split_id order no matter how the visit order was
     optimized or recomposed by the offload cut. An unsorted search has
     EVERY hit tied; truncation at max_hits used to keep whichever docs sat

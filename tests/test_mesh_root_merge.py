@@ -3,14 +3,13 @@
 The collective whole-query program (parallel/fanout.mesh_batch_fn) runs
 score + threshold-exchange + top-K merge + agg reduction ON the mesh and
 reads back one packed scalar array. The claim under test is BIT-IDENTITY
-with the host-merge twin (the single-device fused batch program, whose
-own equivalence with the sequential per-split collector merge is
-test_parallel.py's claim): same hits in the same total order — (key
-desc, split_id asc, doc asc), including tie subsets under truncation —
-same counts, and same agg states, for every mesh shape that divides the
-batch. Around that sit the routing rules that keep the host path alive
-(single-device degenerate, search_after, Tier A/B cache consultation),
-the cross-query mesh-resident stacks (warm multi-split query uploads
+with the host-merge twin — what one device serves: leaf search per split,
+merged by the IncrementalCollector in split-id order — same hits in the
+same total order — (key desc, split_id asc, doc asc), including tie
+subsets under truncation — same counts, and same agg states, for every
+mesh shape that divides the batch (one that does not is refused). Around
+that sit the routing rules that keep the host path alive (search_after,
+Tier A/B cache consultation), the cross-query mesh-resident stacks (warm multi-split query uploads
 zero column bytes to any chip), the chunked × fused interplay, and the
 DST fanout scenario's cache≡cold invariant against the mesh path.
 
@@ -98,11 +97,22 @@ def readers():
         {f"split-{s}": _docs(s) for s in range(N_SPLITS)}, "ram:///meshmerge")
 
 
-def _batch(request, readers, mesh=None, pad_to=None):
+def _batch(request, readers, mesh, pad_to=None):
     ids = sorted(readers.keys())
     batch = build_batch(request, MAPPER, [readers[i] for i in ids], ids,
                        pad_to_splits=pad_to)
-    return execute_batch(batch, request, mesh=mesh)
+    return execute_batch(batch, request, mesh)
+
+
+def _host(request, readers):
+    """The host-merge twin: per-split leaf search, collector merge in
+    split-id order."""
+    coll = IncrementalCollector(max_hits=request.max_hits,
+                                start_offset=request.start_offset)
+    for split_id in sorted(readers):
+        coll.add_leaf_response(leaf_search_single_split(
+            request, MAPPER, readers[split_id], split_id))
+    return coll.to_leaf_response()
 
 
 def _hit_rows(resp):
@@ -163,9 +173,9 @@ MESH_SHAPES = [(2, 1), (4, 2), (8, 1)]
 @pytest.mark.parametrize("req_idx", range(len(REQUESTS)))
 def test_collective_matches_host_merge_bit_identical(readers, shape, req_idx):
     """1/2/4/8-way split sharding (x doc sharding): the on-mesh root merge
-    must equal the single-device host-merge twin exactly."""
+    must equal the host-merge twin exactly."""
     request = REQUESTS[req_idx]
-    host = _batch(request, readers)
+    host = _host(request, readers)
     mesh = _batch(request, readers, mesh=make_mesh(*shape))
     _assert_identical(mesh, host)
 
@@ -194,7 +204,7 @@ def test_all_ties_truncation(readers):
         # tenant_id asc over docs filtered to one severity still carries
         # massive ties; add a constant-ish secondary-free single key
         sort_fields=(SortField("tenant_id", "asc"),))
-    host = _batch(request, readers)
+    host = _host(request, readers)
     for shape in MESH_SHAPES:
         mesh = _batch(request, readers, mesh=make_mesh(*shape))
         _assert_identical(mesh, host)
@@ -203,19 +213,19 @@ def test_all_ties_truncation(readers):
     assert len(set(vals)) < len(vals)
 
 
-def test_nondivisible_mesh_falls_back_to_host_path(readers):
-    """A mesh whose split axis does not divide the batch must drop to the
-    single-device host-merge degenerate (no collective dispatch, no ragged
-    sharding error) and still answer identically."""
+def test_nondivisible_mesh_is_refused(readers):
+    """A mesh whose split axis does not divide the batch has no program:
+    the dispatch raises (the service then goes per split) — no collective
+    dispatch, no ragged sharding error from deep inside staging."""
     from quickwit_tpu.observability.metrics import MESH_DISPATCHES_TOTAL
     request = REQUESTS[0]
     ids = sorted(readers.keys())[:3]          # 3 splits, axis 2: ragged
     sub = {i: readers[i] for i in ids}
-    host = _batch(request, sub)
     before = MESH_DISPATCHES_TOTAL.get()
-    mesh = _batch(request, sub, mesh=make_mesh(2, 1))
-    assert MESH_DISPATCHES_TOTAL.get() == before  # degenerate, not collective
-    _assert_identical(mesh, host)
+    with pytest.raises(ValueError, match="does not shard"):
+        _batch(request, sub, mesh=make_mesh(2, 1))
+    assert MESH_DISPATCHES_TOTAL.get() == before
+    assert not fanout._MESH_DISPATCH_LOCK.locked()
 
 
 def test_padded_batch_on_mesh(readers):
@@ -224,7 +234,7 @@ def test_padded_batch_on_mesh(readers):
     request = REQUESTS[0]
     ids = sorted(readers.keys())[:3]
     sub = {i: readers[i] for i in ids}
-    host = _batch(request, sub, pad_to=4)
+    host = _host(request, sub)
     mesh = _batch(request, sub, mesh=make_mesh(4, 1), pad_to=4)
     _assert_identical(mesh, host)
     assert all(h.split_id for h in mesh.partial_hits)
@@ -243,7 +253,7 @@ def test_collective_across_split_formats(env):
     readers = _build_readers({f"s{i}": _docs(i, 120) for i in range(4)},
                              f"ram:///meshfmt-{tag}", env=env)
     for request in (REQUESTS[0], REQUESTS[1], REQUESTS[4]):
-        host = _batch(request, readers)
+        host = _host(request, readers)
         mesh = _batch(request, readers, mesh=make_mesh(4, 2))
         _assert_identical(mesh, host)
 
@@ -299,7 +309,7 @@ def test_property_seeded_equivalence(readers):
                     "lat": {"stats": {"field": "latency"}}}
         request = SearchRequest(index_ids=["x"], query_ast=q, max_hits=k,
                                 sort_fields=sorts, aggs=aggs)
-        host = _batch(request, readers)
+        host = _host(request, readers)
         got = _batch(request, readers, mesh=mesh)
         _assert_identical(got, host)
 
@@ -327,7 +337,7 @@ def test_warm_stack_zero_column_upload(readers):
         batch = build_batch(request, MAPPER, [readers[i] for i in ids], ids)
         fanout.stage_device_inputs(batch, mesh, resident_store=store,
                                    budget=budget)
-        resp = execute_batch(batch, request, mesh=mesh)
+        resp = execute_batch(batch, request, mesh)
         fanout.release_stack_pin(batch, budget)
         return resp
 

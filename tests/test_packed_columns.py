@@ -348,13 +348,15 @@ def test_packed_results_match_on_c2_style_query(packed_reader, raw_reader):
 # --- batch (fanout) ---------------------------------------------------------
 
 def test_batch_over_packed_splits(packed_reader):
-    from quickwit_tpu.parallel.fanout import build_batch, execute_batch
+    from quickwit_tpu.parallel.fanout import (
+        build_batch, execute_batch, make_mesh,
+    )
     other = build_reader(packed=True, name="s2.split")
     req = SearchRequest(index_ids=["t"], query_ast=c2_style_query(),
                         max_hits=40,
                         sort_fields=[SortField("timestamp", "desc")])
     batch = build_batch(req, MAPPER, [packed_reader, other], ["s1", "s2"])
-    resp = execute_batch(batch, req)
+    resp = execute_batch(batch, req, make_mesh(2, 1))
     single = run(packed_reader, query_ast=c2_style_query(), max_hits=40,
                  sort_fields=[SortField("timestamp", "desc")])
     assert resp.num_hits == 2 * single.num_hits

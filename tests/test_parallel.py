@@ -1,8 +1,9 @@
-"""Multi-split batched + mesh-sharded execution parity.
+"""Multi-split mesh-sharded execution parity.
 
-The merged batch result must equal running leaf search per split and merging
-through the IncrementalCollector (the reference's merge-tree invariant), and
-the mesh-sharded run must equal the single-device run bit-for-bit.
+The collective batch program's merged result must equal what one device
+serves: leaf search per split, merged through the IncrementalCollector in
+split-id order (the reference's merge-tree invariant) — on every mesh
+layout.
 """
 
 import jax
@@ -84,10 +85,11 @@ def reference_merge(request, readers):
 
 
 def batch_result(request, readers, mesh=None, pad_to=None):
+    """The batch on `mesh` (default: two devices on the split axis)."""
     ids = list(readers.keys())
     batch = build_batch(request, MAPPER, [readers[i] for i in ids], ids,
                         pad_to_splits=pad_to)
-    return execute_batch(batch, request, mesh=mesh)
+    return execute_batch(batch, request, mesh or make_mesh(2, 1))
 
 
 REQUESTS = [
@@ -124,11 +126,14 @@ REQUESTS = [
 ]
 
 
+# the two layouts tests/test_tpu_compile.py compiles for a described chip
+@pytest.mark.parametrize("layout", [(4, 1), (2, 2)],
+                         ids=lambda l: f"{l[0]}x{l[1]}")
 @pytest.mark.parametrize("req_idx", range(len(REQUESTS)))
-def test_batch_matches_sequential_merge(readers, req_idx):
+def test_batch_matches_sequential_merge(readers, req_idx, layout):
     request = REQUESTS[req_idx]
     expected = reference_merge(request, readers)
-    got = batch_result(request, readers)
+    got = batch_result(request, readers, make_mesh(*layout))
 
     assert got.num_hits == expected.num_hits
     exp_hits = [(h.split_id, h.doc_id, h.sort_value, h.sort_value2,
@@ -168,20 +173,53 @@ def _normalize(aggs):
     return round_floats(json.loads(json.dumps(aggs, default=float, sort_keys=True)))
 
 
-def test_mesh_sharded_matches_single_device(readers):
+def test_mesh_sharded_matches_sequential_merge(readers):
     n_dev = len(jax.devices())
     assert n_dev >= 8, "tests expect 8 virtual cpu devices (conftest)"
     request = REQUESTS[3]
     mesh = make_mesh(4, 2)  # 4-way split parallel x 2-way doc parallel
-    got_mesh = batch_result(request, readers, mesh=mesh)
-    got_single = batch_result(request, readers)
-    assert got_mesh.num_hits == got_single.num_hits
+    got_mesh = batch_result(request, readers, mesh)
+    expected = reference_merge(request, readers)
+    assert got_mesh.num_hits == expected.num_hits
     assert [(h.split_id, h.doc_id) for h in got_mesh.partial_hits] == \
-        [(h.split_id, h.doc_id) for h in got_single.partial_hits]
+        [(h.split_id, h.doc_id) for h in expected.partial_hits()]
     ma = IncrementalCollector(0); ma.add_leaf_response(got_mesh)
-    sa = IncrementalCollector(0); sa.add_leaf_response(got_single)
     assert _normalize(finalize_aggregations(ma.aggregation_states())) == \
-        _normalize(finalize_aggregations(sa.aggregation_states()))
+        _normalize(finalize_aggregations(expected.aggregation_states()))
+
+
+def test_dispatch_batch_refuses_a_mesh_that_does_not_divide_the_batch(readers):
+    """Four splits on a three-way split axis: no program to run, and no
+    other program is run in its place."""
+    from quickwit_tpu.parallel import fanout
+    request = REQUESTS[0]
+    ids = list(readers.keys())
+    batch = build_batch(request, MAPPER, [readers[i] for i in ids], ids)
+    mesh = make_mesh(3, 1)
+    launches = fanout.SEARCH_KERNEL_LAUNCHES_TOTAL.get()
+    with pytest.raises(ValueError, match="does not shard"):
+        fanout.dispatch_batch(batch, request, mesh)
+    with pytest.raises(ValueError, match="does not shard"):
+        fanout.stage_device_inputs(batch, mesh)
+    assert fanout.SEARCH_KERNEL_LAUNCHES_TOTAL.get() == launches
+    assert not getattr(batch, "_device_inputs", None)
+
+
+def test_fanout_has_one_program_family(readers):
+    """parallel/fanout.py builds one program, the collective whole-query
+    program over a mesh: the one-device fused batch and the query-group
+    family are gone, and every compiled entry is keyed by its Mesh."""
+    from jax.sharding import Mesh
+    from quickwit_tpu.parallel import fanout
+    gone = [name for name in dir(fanout)
+            if name in ("batch_fn", "abstract_batch_program", "_usable_mesh",
+                        "_donate_batch_inputs", "_merge_agg_stack")
+            or "group" in name]
+    assert gone == []
+    batch_result(REQUESTS[0], readers)
+    assert fanout._BATCH_JIT_CACHE
+    assert all(any(isinstance(part, Mesh) for part in key)
+               for key in fanout._BATCH_JIT_CACHE)
 
 
 def test_batch_with_padding_splits(readers):
@@ -189,7 +227,7 @@ def test_batch_with_padding_splits(readers):
     contribute hits or counts."""
     request = REQUESTS[0]
     expected = reference_merge(request, readers)
-    got = batch_result(request, readers, pad_to=6)
+    got = batch_result(request, readers, make_mesh(3, 1), pad_to=6)
     assert got.num_hits == expected.num_hits
     assert all(h.split_id for h in got.partial_hits)
 
@@ -214,7 +252,7 @@ def test_batch_rejects_nonuniform_queries(readers):
     except ValueError:
         return  # expected: non-uniform structure rejected
     # if it built (all splits expanded identically), execution must still work
-    execute_batch(batch, request)
+    execute_batch(batch, request, make_mesh(2, 1))
 
 
 def test_batch_numeric_histogram_origin_alignment():
@@ -234,7 +272,7 @@ def test_batch_numeric_histogram_origin_alignment():
     req = SearchRequest(index_ids=["x"], query_ast=MatchAll(), max_hits=0,
                         aggs={"h": {"histogram": {"field": "v", "interval": 50}}})
     batch = build_batch(req, m, rs, ["a", "b"])
-    resp = execute_batch(batch, req)
+    resp = execute_batch(batch, req, make_mesh(2, 1))
     coll = IncrementalCollector(0)
     coll.add_leaf_response(resp)
     got = {b["key"]: b["doc_count"]
@@ -272,7 +310,7 @@ def test_batch_phrase_with_term_missing_in_one_split():
                         query_ast=FullText("body", "hello world", "phrase"),
                         max_hits=10)
     batch = build_batch(req, m, rs, ["a", "b"])  # "world" absent from split b
-    resp = execute_batch(batch, req)
+    resp = execute_batch(batch, req, make_mesh(2, 1))
     assert resp.num_hits == 1
     assert resp.partial_hits[0].split_id == "a"
 
@@ -336,6 +374,6 @@ def test_batch_dynamic_field_absent_from_one_split():
                         query_ast=Term(field="service", value="gw"),
                         max_hits=10)
     batch = build_batch(req, m, rs, ["a", "b"])
-    resp = execute_batch(batch, req)
+    resp = execute_batch(batch, req, make_mesh(2, 1))
     assert resp.num_hits == 1
     assert resp.partial_hits[0].split_id == "a"

@@ -575,86 +575,3 @@ def test_group_chunked_masks_and_cancels_lanes(big_reader):
     assert isinstance(lane0, CancelledQuery) or (
         isinstance(lane0, dict) and lane0.get("partial"))
     _assert_same(results[2], solos[2])
-
-
-# --- fanout: the query axis over the splits x docs mesh ---------------------
-
-def _batches(readers_keys, request_list, k):
-    from quickwit_tpu.parallel import fanout
-    rds, ids = readers_keys
-    return [fanout.build_batch(req, MAPPER, rds, list(ids))
-            for req in request_list], k
-
-
-@pytest.fixture(scope="module")
-def two_splits():
-    return ([_build_reader(220, 3, "m1.split"),
-             _build_reader(220, 7, "m2.split")], ["m1", "m2"])
-
-
-def _response_key(resp):
-    return (resp.num_hits,
-            [(h.split_id, h.doc_id, h.sort_value, h.sort_value2)
-             for h in resp.partial_hits],
-            repr(sorted(resp.intermediate_aggs.items())))
-
-
-def test_query_group_no_mesh_matches_solo_batches(two_splits):
-    from quickwit_tpu.parallel import fanout
-    reqs = [SearchRequest(index_ids=["t"], query_ast=Term("sev", s),
-                          max_hits=8) for s in SEVS]
-    batches, k = _batches(two_splits, reqs, 8)
-    solos = [fanout.execute_batch(b, r) for b, r in zip(batches, reqs)]
-    group = fanout.execute_query_group(batches, reqs[0])
-    assert len(group) == 3
-    for got, want in zip(group, solos):
-        assert _response_key(got) == _response_key(want)
-
-
-def test_query_group_mesh_matches_solo(two_splits):
-    from quickwit_tpu.parallel import fanout
-    mesh = fanout.make_mesh(2, 2)
-    aggs = {"lat_stats": {"stats": {"field": "lat"}},
-            "sevs": {"terms": {"field": "sev"}}}
-    reqs = [SearchRequest(index_ids=["t"], query_ast=Term("sev", s),
-                          max_hits=8, aggs=aggs,
-                          sort_fields=[SortField("ts", "desc")])
-            for s in SEVS]
-    batches, k = _batches(two_splits, reqs, 8)
-    solos = [fanout.execute_batch(b, r) for b, r in zip(batches, reqs)]
-    group = fanout.execute_query_group(batches, reqs[0], mesh=mesh)
-    for got, want in zip(group, solos):
-        assert _response_key(got) == _response_key(want)
-    key = fanout.group_cache_key(batches, 8, mesh=mesh)
-    assert key in fanout._GROUP_JIT_CACHE
-
-
-def test_query_group_mesh_masks_lanes(two_splits):
-    from quickwit_tpu.parallel import fanout
-    mesh = fanout.make_mesh(2, 1)
-    reqs = [SearchRequest(index_ids=["t"], query_ast=Term("sev", s),
-                          max_hits=8) for s in SEVS]
-    batches, k = _batches(two_splits, reqs, 8)
-    masked0 = QBATCH_MASKED_RIDERS_TOTAL.get()
-    group = fanout.execute_query_group(batches, reqs[0], mesh=mesh,
-                                       valid=[True, False, True])
-    assert group[1] is None
-    assert QBATCH_MASKED_RIDERS_TOTAL.get() - masked0 == 1
-    solos = [fanout.execute_batch(b, r) for b, r in zip(batches, reqs)]
-    assert _response_key(group[0]) == _response_key(solos[0])
-    assert _response_key(group[2]) == _response_key(solos[2])
-
-
-def test_query_group_mesh_validity_is_operand_not_key(two_splits):
-    """Masking a lane must reuse the already-compiled group program — the
-    validity mask is an operand, never part of the compile-cache key."""
-    from quickwit_tpu.parallel import fanout
-    mesh = fanout.make_mesh(2, 1)
-    reqs = [SearchRequest(index_ids=["t"], query_ast=Term("sev", s),
-                          max_hits=6) for s in SEVS]
-    batches, k = _batches(two_splits, reqs, 6)
-    fanout.execute_query_group(batches, reqs[0], mesh=mesh)
-    cache_size = len(fanout._GROUP_JIT_CACHE)
-    fanout.execute_query_group(batches, reqs[0], mesh=mesh,
-                               valid=[False, True, True])
-    assert len(fanout._GROUP_JIT_CACHE) == cache_size

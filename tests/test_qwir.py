@@ -21,7 +21,7 @@ from tools.qwir.audit import (audit_specs, check_closure, default_manifest_path,
                               describe_programs, load_manifest,
                               manifest_from_programs, run_audit)
 
-EXPECTED_PROGRAM_COUNT = 29
+EXPECTED_PROGRAM_COUNT = 25
 
 
 @pytest.fixture(scope="module")

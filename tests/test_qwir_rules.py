@@ -120,16 +120,14 @@ def test_r1_catches_cache_key_drift():
 
 def test_r1_catches_cache_key_drift_in_stacked_program():
     """Key drift planted through the REAL stacked query-group programs:
-    if `executor.stacked_program_cache_key` (or `fanout.group_cache_key`)
-    stops mirroring what the dispatch path actually caches on — e.g. the
+    if `executor.stacked_program_cache_key` stops mirroring what the dispatch path actually caches on — e.g. the
     [Q] validity mask leaking into the key, which would force a
     recompile whenever a rider is shed — R1 must flag exactly the
     drifted stacked entry, not its neighbours."""
     from tools.qwir.corpus import build_corpus
     stacked = [s for s in build_corpus()
-               if s.name.startswith(("stacked/", "stacked_chunked/",
-                                     "group_mesh/"))]
-    assert len(stacked) == 3, "expected the three stacked corpus entries"
+               if s.name.startswith(("stacked/", "stacked_chunked/"))]
+    assert len(stacked) == 2, "expected the two stacked corpus entries"
     programs = describe_programs(stacked)
     pinned = manifest_from_programs(programs)
     drifted = {k: dict(v) for k, v in programs.items()}

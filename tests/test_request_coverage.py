@@ -365,33 +365,27 @@ def test_mask_fill_program_name_and_one_outer_scope(reader):
     assert any("term_mask" in names for names in scoped)
 
 
-def test_fused_batch_and_group_program_names(reader):
-    readers, split_ids = [reader] * 2, ["s0", "s1"]
-    group = [fanout.build_batch(
+def test_mesh_batch_program_name(reader):
+    batch = fanout.build_batch(
         SearchRequest(index_ids=["hdfs-logs"], max_hits=10, aggs=AGGS,
-                      query_ast=ERROR.boost(1.0 + lane)),
-        HDFS_MAPPER, readers, split_ids) for lane in range(2)]
-    batch = group[0]
+                      query_ast=ERROR),
+        HDFS_MAPPER, [reader] * 2, ["s0", "s1"])
     args = (tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
                   for a in batch.arrays),
             tuple(jax.ShapeDtypeStruct(s.shape, s.dtype)
                   for s in batch.scalars),
             jax.ShapeDtypeStruct(batch.num_docs.shape, batch.num_docs.dtype))
-    jitted, _, _, _ = fanout._batch_executor(batch, 10, None, args)
-    module, scopes = _module_and_scopes(jitted, args)
-    assert module == "jit_qw_batch_s2_k10"
+    jitted, _, _, _ = fanout._batch_executor(batch, 10,
+                                             fanout.make_mesh(2, 1), args)
+    text = jitted.lower(*args).as_text(debug_info=True)
+    assert re.search(r"module @(\S+)", text).group(1) == \
+        "jit_qw_batch_s2_k10_mesh2"
+    # inside shard_map the name stack starts anew: the per-split stages'
+    # paths carry no `jit(...)` prefix there (file paths start with "/")
+    scopes = set()
+    for path in re.findall(r'loc\("([^"/][^"]*)"', text):
+        scopes.update(_scopes_of(path))
     assert {"bm25_score", "sort_key", "topk", "aggs", "pack"} <= scopes
-    shared_slots, stacked_slots = fanout.group_slot_split(group)
-    stacked_arrays, scalars_b = fanout._stack_group_operands(group,
-                                                             stacked_slots)
-    group_args = (
-        tuple(args[0][slot] for slot in shared_slots),
-        tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in stacked_arrays),
-        tuple(jax.ShapeDtypeStruct(s.shape, s.dtype) for s in scalars_b),
-        args[2], jax.ShapeDtypeStruct((2,), np.bool_))
-    jitted, _, _ = fanout._group_executor(group, 10, None)
-    module, _ = _module_and_scopes(jitted, group_args)
-    assert module == "jit_qw_group_q2_s2_k10"
 
 
 def test_scalars_change_neither_the_name_nor_the_number_of_programs(reader,
@@ -414,13 +408,13 @@ def test_scalars_change_neither_the_name_nor_the_number_of_programs(reader,
     assert executor._PACKED_CACHE[key][0].__name__ == "qw_solo_k10"
 
 
-def test_the_fused_batch_family_counts_its_launches(reader, launches):
+def test_the_mesh_batch_family_counts_its_launches(reader, launches):
     batch = fanout.build_batch(
         SearchRequest(index_ids=["hdfs-logs"], max_hits=5, query_ast=ERROR),
         HDFS_MAPPER, [reader] * 2, ["s0", "s1"])
     response = fanout.readback_batch(fanout.dispatch_batch(
         batch, SearchRequest(index_ids=["hdfs-logs"], max_hits=5,
-                             query_ast=ERROR)))
+                             query_ast=ERROR), fanout.make_mesh(2, 1)))
     assert response.num_hits > 0
     assert len(launches) == 1
 

@@ -8,8 +8,8 @@ made here by a `device_mesh` that returns None, which is all the service
 asks of the device count. On such a node a group never reaches
 `fanout.build_batch`: its splits run as a wave, one worker each, and the
 calling thread merges the answers in split-id order. The claim under test
-is that the wave's answers EQUAL the fused program's (`build_batch` +
-`dispatch_batch` + `readback_batch` called directly) — hits, sort values,
+is that the wave's answers EQUAL the mesh program's (`build_batch` +
+`execute_batch` called directly, one split a device) — hits, sort values,
 counts, aggregation states, the kept subset of an all-ties sort — and that
 failure, shed, cancel and deadline semantics hold split by split.
 
@@ -112,12 +112,14 @@ def _leaf(service, corpus, request, splits=None, **kwargs):
         splits=list(corpus[2] if splits is None else splits), **kwargs))
 
 
-def _fused(corpus, request):
-    """The fused program over the four splits, called directly."""
+def _fused(corpus, request, ids=None):
+    """The mesh program over the splits, one a device, called directly."""
     readers = corpus[1]
+    ids = SPLIT_IDS if ids is None else ids
     batch = fanout.build_batch(request, MAPPER,
-                               [readers[i] for i in SPLIT_IDS], SPLIT_IDS)
-    return fanout.readback_batch(fanout.dispatch_batch(batch, request, None))
+                               [readers[i] for i in ids], ids)
+    return fanout.execute_batch(batch, request,
+                                fanout.make_mesh(len(ids), 1))
 
 
 def _hit_rows(response):
@@ -271,13 +273,9 @@ def test_a_failing_split_is_one_error_beside_the_other_answers(
         [(SPLIT_IDS[2], True)]
     assert "injected" in got.failed_splits[0].error
     assert got.num_successful_splits == N_SPLITS - 1
-    # the others' answers: the fused program over the three that ran
-    readers = corpus[1]
-    ids = [i for i in SPLIT_IDS if i != SPLIT_IDS[2]]
-    batch = fanout.build_batch(TIMESTAMP_SORTED, MAPPER,
-                               [readers[i] for i in ids], ids)
-    want = fanout.readback_batch(
-        fanout.dispatch_batch(batch, TIMESTAMP_SORTED, None))
+    # the others' answers: the mesh program over the three that ran
+    want = _fused(corpus, TIMESTAMP_SORTED,
+                  [i for i in SPLIT_IDS if i != SPLIT_IDS[2]])
     assert got.num_hits == want.num_hits
     assert _hit_rows(got) == _hit_rows(want)
 

@@ -3,8 +3,8 @@ accepted by the chip's compiler, checked here with no chip attached.
 
 The TPU compiler is installed in the sandbox and compiles for a topology
 that is described, not attached (`on-chip-measurement` guide §2). The CPU
-backend accepts what the TPU refuses — a 64-bit max/min all-reduce, a
-misaligned kernel tile — so these are the cases no other tier-1 test can
+backend accepts what the TPU refuses — a 64-bit max/min all-reduce — so
+these are the cases no other tier-1 test can
 see; the two mesh cases are the regression test for the 64-bit collective
 repair in `parallel/fanout.py`. A compile that passes is a compile, never
 a chip run.
@@ -21,8 +21,7 @@ import os
 import jax
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P, \
-    SingleDeviceSharding
+from jax.sharding import SingleDeviceSharding
 
 from quickwit_tpu.common.uri import Uri
 from quickwit_tpu.index.reader import SplitReader
@@ -49,7 +48,7 @@ def _window(lo_days: float, hi_days: float) -> Range:
                  upper=RangeBound(T0_US + int(hi_days * DAY_US), False))
 
 
-# the benchmark's request shapes (bench.py `_workloads`), plus the f64
+# the benchmark's request shapes (benchmark/shapes/), plus the f64
 # sort-key path: a term query sorted by timestamp
 REQUESTS = {
     "flagship": SearchRequest(index_ids=["hdfs-logs"], query_ast=ERROR,
@@ -170,24 +169,10 @@ def test_mask_fill_compiles(reader, one_chip, no_persistent_cache):
              _plan_args(plan, one_chip))
 
 
-def test_pallas_kernel_compiles_not_interpreted(one_chip,
-                                                no_persistent_cache):
-    from quickwit_tpu.ops.pallas.score_topk import BLOCK, fused_score_topk
-    postings = 4 * BLOCK
-    i32 = jax.ShapeDtypeStruct((postings,), np.int32, sharding=one_chip)
-    f32 = jax.ShapeDtypeStruct((), np.float32, sharding=one_chip)
-    nd = jax.ShapeDtypeStruct((), np.int32, sharding=one_chip)
-    text = _compile(
-        jax.jit(lambda ids, tfs, norms, idf, avg, n: fused_score_topk(
-            ids, tfs, norms, idf, avg, n, k=10, interpret=False)),
-        (i32, i32, i32, f32, f32, nd))
-    assert "tpu_custom_call" in text
-
-
 @pytest.mark.parametrize("axis_splits,axis_docs", [(4, 1), (2, 2)])
 def test_mesh_programs_compile(axis_splits, axis_docs, topo, reader,
                                no_persistent_cache):
-    """`mesh_batch_fn` and `group_mesh_fn` on a described four-chip mesh.
+    """`mesh_batch_fn` on a described four-chip mesh, in both layouts.
     The TPU compiler lowers only *sum* all-reduces on 64-bit types; the
     f64 threshold exchange, certificate and agg min/max must reach it as
     all_gather + local reduce (`fanout._all_reduce_extremum`)."""
@@ -195,11 +180,10 @@ def test_mesh_programs_compile(axis_splits, axis_docs, topo, reader,
     readers, split_ids = [reader] * 4, [f"s{i}" for i in range(4)]
     # stats carries a 64-bit min and max through the agg merge as well
     aggs = {**AGGS, "tenants": {"stats": {"field": "tenant_id"}}}
-    group = [fanout.build_batch(
+    batch = fanout.build_batch(
         SearchRequest(index_ids=["hdfs-logs"], max_hits=10, aggs=aggs,
-                      query_ast=ERROR.boost(1.0 + lane)),
-        HDFS_MAPPER, readers, split_ids) for lane in range(2)]
-    batch = group[0]
+                      query_ast=ERROR),
+        HDFS_MAPPER, readers, split_ids)
     arrays_sh, scalars_sh, nd_sh = fanout.batch_shardings(batch, mesh)
     num_docs = jax.ShapeDtypeStruct(batch.num_docs.shape,
                                     batch.num_docs.dtype, sharding=nd_sh)
@@ -212,19 +196,3 @@ def test_mesh_programs_compile(axis_splits, axis_docs, topo, reader,
                                                            args)
     assert meta["collective_bytes"] > 0
     _compile(jitted, args)
-
-    shared_slots, stacked_slots = fanout.group_slot_split(group)
-    stacked_arrays, scalars_b = fanout._stack_group_operands(group,
-                                                             stacked_slots)
-    lanes_sh = NamedSharding(mesh, P(None, mesh.axis_names[0]))
-    group_args = (
-        tuple(args[0][slot] for slot in shared_slots),
-        tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=lanes_sh)
-              for a in stacked_arrays),
-        tuple(jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=lanes_sh)
-              for s in scalars_b),
-        num_docs,
-        jax.ShapeDtypeStruct((len(group),), np.bool_,
-                             sharding=NamedSharding(mesh, P())))
-    jitted, _treedef, _spec = fanout._group_executor(group, 10, mesh)
-    _compile(jitted, group_args)

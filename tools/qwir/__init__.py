@@ -5,7 +5,7 @@ qwlint (tools/qwlint) checks the *source*; qwmc (tools/qwmc) checks the
 actually runs. It abstract-evals (never executes, never compiles) a
 representative plan corpus — `search/plan.py` lowerings enumerated across
 format versions, padding buckets, threshold/mask_override/count_override
-variants, single-split / multi-query / fused-batch / mask-fill paths —
+variants, single-split / multi-query / mesh-batch / mask-fill paths —
 and runs five rules over the resulting jaxprs:
 
   R1 compile-cache-closure  the set of (cache key, jaxpr digest) pairs

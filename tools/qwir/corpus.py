@@ -10,13 +10,13 @@ then enumerates the lowered-program surface of the hot path:
     sorts, search_after pushdown, threshold pushdown (impact prefix +
     count_override), mask_override (PMaskRef), exact fallbacks
   - multi-query vmapped programs per batch bucket
-  - fused multi-split batch programs (parallel/fanout.py, with and
+  - collective mesh batch programs (parallel/fanout.py, with and
     without 2-key / agg merges)
   - the Tier-A predicate-mask fill kernel
 
 Every entry abstract-traces through the SAME build closures the dispatch
 paths jit (executor.abstract_program / abstract_multi_program /
-abstract_mask_fill, fanout.abstract_batch_program) and records the
+abstract_mask_fill, fanout.abstract_mesh_batch_program) and records the
 mirrored compile-cache key — the R1 closure certificate is over exactly
 the keys the runtime caches key on.
 
@@ -113,7 +113,7 @@ def _build_reader(mapper, docs, name: str, env: Optional[dict] = None):
 @dataclass
 class ProgramSpec:
     name: str                 # stable corpus id, e.g. "single/v3/term/k10"
-    kind: str                 # single | multi | batch | mask_fill
+    kind: str                 # single | multi | mesh | mask_fill
     closed: Any               # ClosedJaxpr (abstract trace, never executed)
     cache_key: tuple          # the runtime compile-cache key, mirrored
     doc_lanes: int            # total padded doc lanes across vmap/batch dims
@@ -306,42 +306,14 @@ def build_corpus() -> list[ProgramSpec]:
         doc_lanes=sev_chunks[0].num_docs_padded * 2,
         num_docs_padded=sev_chunks[0].num_docs_padded))
 
-    # -- fused multi-split batch programs (parallel/fanout.py) -----------
-    from quickwit_tpu.search import SearchRequest, SortField
-
-    def batch_spec(name, request, k, split_keys, aggs_note=""):
-        rds = [readers[s] for s in split_keys]
-        batch = fanout.build_batch(request, mapper, rds, list(split_keys))
-        closed = fanout.abstract_batch_program(batch, k)
-        specs.append(ProgramSpec(
-            name=name, kind="batch", closed=closed,
-            cache_key=fanout.batch_cache_key(batch, k, mesh=None),
-            doc_lanes=batch.num_docs_padded * batch.n_splits,
-            num_docs_padded=batch.num_docs_padded))
-
-    batch_spec("batch/v3/term/n2/k10",
-               SearchRequest(index_ids=["t"], query_ast=term, max_hits=10),
-               10, ("v3", "v3b"))
-    batch_spec("batch/v3/sort_2key/n2/k5",
-               SearchRequest(index_ids=["t"], query_ast=match_all, max_hits=5,
-                             sort_fields=[SortField("latency", "desc"),
-                                          SortField("timestamp", "asc")]),
-               5, ("v3", "v3b"))
-    batch_spec("batch/v3/aggs/n2/k0",
-               SearchRequest(
-                   index_ids=["t"], query_ast=match_all, max_hits=0,
-                   aggs={"per_hour": {
-                       "date_histogram": {"field": "timestamp",
-                                          "fixed_interval": "1h"},
-                       "aggs": {"lat_avg": {"avg": {"field": "latency"}}}}}),
-               0, ("v3", "v3b"))
-
     # -- collective mesh root-merge programs (parallel/fanout.py) --------
     # the whole-query shard_map programs: per-shard scoring, the
     # all-reduce-max threshold exchange, the all_gather + re-top-k merge,
     # and the psum/max/min agg reduction are EXPLICIT collective eqns here —
     # R4's mesh-axis rule audits every one against the declared
     # ("splits", "docs") axes
+    from quickwit_tpu.search import SearchRequest, SortField
+
     def mesh_spec(name, request, k, split_keys, mesh):
         rds = [readers[s] for s in split_keys]
         batch = fanout.build_batch(request, mapper, rds, list(split_keys))
@@ -370,40 +342,6 @@ def build_corpus() -> list[ProgramSpec]:
                                          "fixed_interval": "1h"},
                       "aggs": {"lat_avg": {"avg": {"field": "latency"}}}}}),
               0, ("v3", "v3b"), mesh21)
-
-    # -- stacked query-group mesh program (query axis x splits x docs) ---
-    # Q distinct queries over the SAME split set fused into one shard_map
-    # dispatch: the query axis is vmapped inside every device shard, and
-    # the threshold exchange / all_gather merge / segment agg
-    # reduction run per query lane — R4 audits the collectives against the
-    # same ("splits", "docs") axes as the single-query mesh programs.
-    # Range windows over the timestamp zonemap are shape-compatible by
-    # construction (scalar bounds only; no per-query array operands).
-    from quickwit_tpu.query.ast import Range as _Range, \
-        RangeBound as _RangeBound
-
-    def _window(lo_min, hi_min):
-        return _Range("timestamp",
-                      lower=_RangeBound((T0 + 60 * lo_min) * 10**6, True),
-                      upper=_RangeBound((T0 + 60 * hi_min) * 10**6, False))
-
-    group_batches = [
-        fanout.build_batch(
-            SearchRequest(index_ids=["t"], query_ast=_window(lo, hi),
-                          max_hits=10,
-                          sort_fields=[SortField("timestamp", "desc")]),
-            mapper, [readers["v3"], readers["v3b"]], ["v3", "v3b"])
-        for (lo, hi) in ((0, 120), (40, 200))]
-    group_sigs = {b.template.signature(10) for b in group_batches}
-    assert len(group_sigs) == 1, \
-        "corpus query-group lanes must be shape-compatible"
-    closed = fanout.abstract_group_mesh_program(group_batches, 10, mesh21)
-    specs.append(ProgramSpec(
-        name="group_mesh/v3/range/q2/n2/2x1/k10", kind="mesh", closed=closed,
-        cache_key=fanout.group_cache_key(group_batches, 10, mesh=mesh21),
-        doc_lanes=(group_batches[0].num_docs_padded
-                   * group_batches[0].n_splits * 2),
-        num_docs_padded=group_batches[0].num_docs_padded))
 
     # -- Tier-A predicate-mask fill kernel -------------------------------
     plan = lower_request(bool_range, mapper, readers["v3"], [],

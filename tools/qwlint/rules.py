@@ -15,10 +15,6 @@ from .core import FileContext, Finding, dotted_name, last_segment
 
 _HOT_PATH_MODULES = (
     "quickwit_tpu/ops/",
-    # explicit even though the ops/ prefix already covers it: the Pallas
-    # kernels are the single hottest code in the tree and must not fall
-    # out of scope if they ever move out of ops/
-    "quickwit_tpu/ops/pallas/",
     # compaction merges re-run the impact quantizer over every surviving
     # posting; a hidden readback or per-merge jit there multiplies by the
     # merge fan-in, not the query rate
