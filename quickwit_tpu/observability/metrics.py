@@ -286,6 +286,17 @@ IMPACT_PREFIX_CUTOFFS_TOTAL = METRICS.counter(
     "qw_impact_prefix_cutoffs_total",
     "Plan lowerings that truncated a term's postings to the live prefix")
 
+# --- plan lowering (search/plan.py) -----------------------------------------
+# Per split and term lowered: a dense term (df >= num_docs / 32) as a
+# resident per-doc tf lane, or kept as its posting list (sparse terms, a
+# lone-term root's posting-space path, the mesh batch).
+PLAN_TERM_LANES_TOTAL = METRICS.counter(
+    "qw_plan_term_lanes_total",
+    "Terms lowered to a resident per-doc tf lane (PTermLane), per split")
+PLAN_TERM_POSTINGS_TOTAL = METRICS.counter(
+    "qw_plan_term_postings_total",
+    "Terms lowered to their posting list (PPostings), per split")
+
 # --- per-query execution profiles (observability/profile.py) ---------------
 # Wall time per waterfall phase, labeled phase=<name> (plan_build,
 # admission_wait, batcher_queue_wait, storage_read, staging, compile,

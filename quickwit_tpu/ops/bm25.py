@@ -34,6 +34,24 @@ def score_postings(tfs: jnp.ndarray, doc_ids: jnp.ndarray,
     """
     tf = tfs.astype(jnp.float32)
     norms = fieldnorms[jnp.clip(doc_ids, 0, fieldnorms.shape[0] - 1)].astype(jnp.float32)
+    return _bm25(tf, norms, avg_len, idf_value, boost)
+
+
+def score_lanes(lane: jnp.ndarray, fieldnorms: jnp.ndarray, avg_len: float,
+                idf_value: float, boost: float = 1.0) -> jnp.ndarray:
+    """Per-doc BM25 scores (float32, [num_docs_padded]) of a term held as a
+    resident tf lane (search/plan.py::PTermLane): the same expression as
+    `score_postings`, read in place over every doc, zero where the term is
+    absent (tf == 0) — what scattering `score_postings` into zeros gives,
+    since each matching doc receives exactly one partial and `0 + x == x`.
+    """
+    scores = _bm25(lane.astype(jnp.float32), fieldnorms.astype(jnp.float32),
+                   avg_len, idf_value, boost)
+    return jnp.where(lane > 0, scores, jnp.float32(0.0))
+
+
+def _bm25(tf, norms, avg_len, idf_value, boost):
+    """THE BM25 expression over f32 tf and fieldnorm operands."""
     denom = tf + K1 * (1.0 - B + B * norms / jnp.maximum(avg_len, 1e-9))
     return (boost * idf_value * (K1 + 1.0)) * tf / jnp.maximum(denom, 1e-9)
 

@@ -5,8 +5,8 @@ Role of the reference's `searcher.search(&query, &collector)` box
 aggregations on a rayon pool): here the whole box is **one XLA program**
 assembled from the LoweredPlan:
 
-    masks = scatter(postings)         # ops/masks.py
-    scores = scatter-add(bm25(tfs))   # ops/bm25.py
+    masks = scatter(postings)         # ops/masks.py; a dense term's
+    scores = scatter-add(bm25(tfs))   # ops/bm25.py;  tf lane is read in place
     bool combine = elementwise VPU ops
     top-k = lax.top_k over dense keys # ops/topk.py
     aggs = scatter-add bucket states  # ops/aggs.py
@@ -38,11 +38,11 @@ from ..ops import masks as mask_ops
 from ..ops import topk as topk_ops
 from ..observability import flight
 from ..observability.metrics import SEARCH_KERNEL_LAUNCHES_TOTAL
-from ..ops.bm25 import dequantize_block_bounds, score_postings
+from ..ops.bm25 import dequantize_block_bounds, score_lanes, score_postings
 from .plan import (
     PRESENT_FROM_VALUES, BucketAggExec, CompositeAggExec, LoweredPlan,
     MetricAggExec, PBool, PMaskRef, PMatchAll, PMatchNone, PNormPresence,
-    PPostings, PPresence, PRange, SortExec,
+    PPostings, PPresence, PRange, PTermLane, SortExec,
 )
 
 _JIT_CACHE: dict[tuple, Callable] = {}
@@ -713,6 +713,18 @@ def _node_evaluator(padded: int) -> Callable:
                     arrays[node.tfs_slot], ids, arrays[node.norm_slot],
                     scalars[node.avg_len_slot], scalars[node.idf_slot])
                 scores = mask_ops.dense_from_postings(ids, partial, padded)
+            return mask, scores
+        if isinstance(node, PTermLane):
+            # a dense term read in place, one lane element a doc: no scatter
+            lane = arrays[node.lane_slot]
+            with jax.named_scope(SCOPE_TERM_MASK):
+                mask = lane > 0
+            if not node.scoring:
+                return mask, None
+            with jax.named_scope(SCOPE_BM25_SCORE):
+                scores = score_lanes(
+                    lane, arrays[node.norm_slot],
+                    scalars[node.avg_len_slot], scalars[node.idf_slot])
             return mask, scores
         if isinstance(node, PRange):
             with jax.named_scope(SCOPE_RANGE_FILTER):

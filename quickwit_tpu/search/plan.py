@@ -5,6 +5,8 @@ tantivy Query + WarmupInfo): against a concrete split, resolve every AST node
 into a **static-structure plan** over named device arrays:
 
 - terms resolve to padded posting arrays (ids/tfs) + per-term idf scalars,
+  or, where dense (df >= num_docs / TERM_LANE_DF_RATIO), to a resident
+  per-doc tf lane,
 - ranges resolve to column slots + traced bound scalars,
 - phrases are pre-matched host-side (`ops/phrase.py`) into precomputed
   posting arrays,
@@ -40,6 +42,9 @@ from ..query.aggregations import (
 from ..query.tokenizers import get_tokenizer
 from ..index.impact import IMPACT_BLOCK
 from ..index.reader import SplitReader, TermInfo
+from ..observability.metrics import (
+    PLAN_TERM_LANES_TOTAL, PLAN_TERM_POSTINGS_TOTAL,
+)
 from ..utils.datetime_utils import parse_datetime_to_micros
 
 import logging
@@ -52,6 +57,12 @@ _ANALYZER_WARN = RateLimitedLog(limit=3, period_secs=300.0)
 
 MAX_EXPANSIONS = 1024
 MAX_BUCKETS = 65536  # reference: AggregationLimitsGuard default bucket limit
+# A term whose df * TERM_LANE_DF_RATIO >= num_docs lowers to a resident
+# per-doc tf lane (PTermLane) instead of its postings: the bitmap/array
+# crossing for 32-bit doc ids, where a posting list costs as many bytes as
+# one bit a doc — and a scatter of P postings into a [num_docs_padded]
+# array costs far more than reading one byte a doc in place.
+TERM_LANE_DF_RATIO = 32
 
 
 class PlanError(ValueError):
@@ -97,6 +108,24 @@ class PPostings:
         return (f"post({self.ids_slot},{self.tfs_slot},{self.scoring},"
                 f"{self.norm_slot},{self.impact_bmax_slot},"
                 f"{self.impact_ordered})")
+
+
+@dataclass(frozen=True)
+class PTermLane:
+    """A dense term (df >= num_docs / TERM_LANE_DF_RATIO) as a resident
+    `[num_docs_padded]` tf lane of the narrowest unsigned dtype that holds
+    its largest tf (0 = absent): the mask is `lane > 0` and the BM25 score
+    the postings' expression read in place (`ops/bm25.py::score_lanes`),
+    with no scatter. The lane's dtype reaches the signature through the
+    plan's array shapes; no posting length does."""
+    lane_slot: int
+    scoring: bool
+    norm_slot: int = -1     # dense fieldnorm column (scoring only)
+    idf_slot: int = -1      # traced scalar: idf * boost
+    avg_len_slot: int = -1  # traced scalar
+
+    def sig(self) -> str:
+        return f"lane({self.lane_slot},{self.scoring},{self.norm_slot})"
 
 
 @dataclass(frozen=True)
@@ -465,7 +494,7 @@ class Lowering:
 
     def __init__(self, doc_mapper: DocMapper, reader: SplitReader,
                  batch_overrides: Optional[dict] = None,
-                 absence_sink=None):
+                 absence_sink=None, term_lanes: bool = False):
         self.doc_mapper = doc_mapper
         self.reader = reader
         self.b = _Builder(reader)
@@ -486,6 +515,11 @@ class Lowering:
         self._impact_term: Optional[tuple[str, str, float]] = None
         self._impact_threshold: Optional[float] = None
         self.count_override: Optional[int] = None
+        # dense terms lower to PTermLane where lower_request says so; the
+        # tallies feed qw_plan_term_{lanes,postings}_total
+        self.term_lanes = term_lanes
+        self.lane_terms = 0
+        self.posting_terms = 0
 
     # --- helpers ----------------------------------------------------------
     def _field(self, name: str) -> FieldMapping:
@@ -527,6 +561,11 @@ class Lowering:
             if self.batch is None:
                 return PMatchNone()
             return self._empty_postings_node(field, term, scoring)
+        if (self.term_lanes
+                and info.df * TERM_LANE_DF_RATIO >= self.reader.num_docs):
+            self.lane_terms += 1
+            return self._lane_node(field, info, scoring, boost)
+        self.posting_terms += 1
         impact_ordered = self.reader.impact_info(field) is not None
         prefix = None
         if (scoring and impact_ordered and self.batch is None
@@ -559,11 +598,8 @@ class Lowering:
         if not scoring:
             return PPostings(ids_slot, tfs_slot, scoring=False,
                              impact_ordered=impact_ordered)
-        meta = self.reader.field_meta(field)
-        norm_slot = self._fieldnorm_slot(field)
-        idf_value = bm25_idf(self.reader.num_docs, info.df) * boost
-        idf_slot = self.b.add_scalar(idf_value, np.float32)
-        avg_slot = self.b.add_scalar(meta.get("avg_len", 1.0), np.float32)
+        norm_slot, idf_slot, avg_slot = self._bm25_slots(field, info.df,
+                                                         boost)
         bmax_slot = scale_slot = -1
         if prefix is not None:
             live_blocks = prefix["live_blocks"]
@@ -579,6 +615,29 @@ class Lowering:
                          avg_slot, impact_bmax_slot=bmax_slot,
                          impact_scale_slot=scale_slot,
                          impact_ordered=impact_ordered)
+
+    def _bm25_slots(self, field: str, df: int,
+                    boost: float) -> tuple[int, int, int]:
+        """(norm_slot, idf_slot, avg_len_slot) of one scoring term."""
+        meta = self.reader.field_meta(field)
+        norm_slot = self._fieldnorm_slot(field)
+        idf_value = bm25_idf(self.reader.num_docs, df) * boost
+        idf_slot = self.b.add_scalar(idf_value, np.float32)
+        avg_slot = self.b.add_scalar(meta.get("avg_len", 1.0), np.float32)
+        return norm_slot, idf_slot, avg_slot
+
+    def _lane_node(self, field: str, info: "TermInfo", scoring: bool,
+                   boost: float) -> PTermLane:
+        """A dense term as its resident tf lane: the term's postings are
+        not referenced, so the lane replaces them on the device."""
+        reader = self.reader
+        lane_slot = self.b.add_array(
+            f"lane.{field}.{info.ordinal}",
+            lambda: term_lane(reader, field, info))
+        if not scoring:
+            return PTermLane(lane_slot, scoring=False)
+        return PTermLane(lane_slot, True,
+                         *self._bm25_slots(field, info.df, boost))
 
     def _impact_prefix(self, field: str, info: "TermInfo", boost: float):
         """Host-side prefix-cutoff decision for one impact-ordered term:
@@ -1756,6 +1815,30 @@ def ordinalize_numeric_column(reader: SplitReader, field: str):  # qwlint: disab
     return result
 
 
+def term_lane(reader: SplitReader, field: str, info: TermInfo) -> np.ndarray:  # qwlint: disable=QW001 - int() of the host max over the reader's numpy tfs, sizing the lane's dtype at plan-build time
+    """The `[num_docs_padded]` tf lane of one term (0 where absent, pads
+    included), in the narrowest unsigned dtype that holds its largest tf.
+    Built from the postings once per (split, term) and cached on the
+    reader, like the derived seconds columns: `_Builder.add_array` fetches
+    on every lowering."""
+    cache_key = f"_lane.{field}.{info.ordinal}"
+    cache = getattr(reader, "_dyn_cache", None)
+    if cache is None:
+        cache = reader._dyn_cache = {}
+    lane = cache.get(cache_key)
+    if lane is None:
+        ids, tfs = reader.postings(field, info)
+        real = ids < reader.num_docs   # pad postings carry num_docs_padded
+        ids, tfs = ids[real], tfs[real]
+        top = int(tfs.max()) if tfs.size else 0
+        dtype = next(dt for dt in (np.uint8, np.uint16, np.uint32)
+                     if top <= np.iinfo(dt).max)
+        lane = np.zeros(reader.num_docs_padded, dtype=dtype)
+        lane[ids] = tfs
+        cache[cache_key] = lane
+    return lane
+
+
 def _wildcard_prefix(pattern: str) -> str:
     for i, ch in enumerate(pattern):
         if ch in "*?[":
@@ -1798,7 +1881,18 @@ def lower_request(
     predicate column is fetched or staged. Sort and agg columns lower as
     usual. `mask_key` keys the mask's array slot so warm splits reuse its
     device copy through `ResidentColumnStore` like any column."""
-    low = Lowering(doc_mapper, reader, batch_overrides, absence_sink)
+    node = query_ast
+    while isinstance(node, Q.Boost):
+        node = node.underlying
+    # Dense terms become tf lanes, except under a batch (its stacks know
+    # posting keys only) and where the root may be a lone term with no
+    # search_after: the posting-space path (P << N, and the impact cutoff)
+    # serves that root.
+    term_lanes = batch_overrides is None and (
+        isinstance(node, Q.Bool) or search_after is not None
+        or start_timestamp is not None or end_timestamp is not None)
+    low = Lowering(doc_mapper, reader, batch_overrides, absence_sink,
+                   term_lanes=term_lanes)
     scoring = "_score" in (sort_field, sort2_field)
     if mask_override is not None:
         if scoring:
@@ -1817,9 +1911,6 @@ def lower_request(
         # scoring term — a bare Term/FullText (possibly boosted), never a
         # Bool, so no filter/should sibling can rescue a dropped posting
         # and the term's df is the exact matched-doc count
-        node = query_ast
-        while isinstance(node, Q.Boost):
-            node = node.underlying
         if isinstance(node, (Q.Term, Q.FullText)):
             from .pruning import scoring_terms
             terms = scoring_terms(query_ast, doc_mapper)
@@ -1838,6 +1929,10 @@ def lower_request(
             upper=Q.RangeBound(end_timestamp, False) if end_timestamp is not None else None,
         ), bounds_are_micros=True)
         root = PBool(must=(root,), filter=(ts_node,))
+    if low.lane_terms:
+        PLAN_TERM_LANES_TOTAL.inc(low.lane_terms)
+    if low.posting_terms:
+        PLAN_TERM_POSTINGS_TOTAL.inc(low.posting_terms)
     return _finish_lowering(low, root, reader, agg_specs, sort_field,
                             sort_order, sort2_field, sort2_order,
                             search_after, sort_value_threshold)
@@ -1897,6 +1992,10 @@ def _query_node_slots(node: Any, out: set[int]) -> None:
     if isinstance(node, PPostings):
         for slot in (node.ids_slot, node.tfs_slot, node.norm_slot,
                      node.impact_bmax_slot):
+            if slot >= 0:
+                out.add(slot)
+    elif isinstance(node, PTermLane):
+        for slot in (node.lane_slot, node.norm_slot):
             if slot >= 0:
                 out.add(slot)
     elif isinstance(node, PRange):
@@ -1978,7 +2077,7 @@ class ChunkSlotPlan:
       filtered to the chunk's doc window and rebased host-side (out-of-
       window lanes get the chunk's OOB scatter sentinel).
     - `doc_slots`: per-padded-doc columns (values, presence, fieldnorms,
-      ordinals) — sliced `[base : base + span]`.
+      ordinals, term tf lanes) — sliced `[base : base + span]`.
     - `zone_slots`: per-ZONEMAP_BLOCK zonemaps — sliced by block index.
     - `packed_slots`: np.packbits doc bitmasks — sliced by byte index.
     - `full_slots`: bounded non-doc tables (range-agg bounds, per-ordinal
@@ -2010,6 +2109,11 @@ def chunk_slot_plan(plan: LoweredPlan) -> Optional[ChunkSlotPlan]:
                 doc.add(node.norm_slot)
             if node.impact_bmax_slot >= 0:
                 full.add(node.impact_bmax_slot)
+            return True
+        if isinstance(node, PTermLane):
+            doc.add(node.lane_slot)
+            if node.norm_slot >= 0:
+                doc.add(node.norm_slot)
             return True
         if isinstance(node, PRange):
             doc.add(node.values_slot)

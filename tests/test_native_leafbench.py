@@ -36,7 +36,7 @@ def _native_cpu_bool_range(plan, request, reference_count: int,
     import ctypes
 
     import numpy as np
-    from quickwit_tpu.search.plan import PBool, PPostings, PRange
+    from quickwit_tpu.search.plan import PBool, PPostings, PRange, PTermLane
 
     lib = load_leafbench()
     k = request.start_offset + request.max_hits
@@ -48,21 +48,32 @@ def _native_cpu_bool_range(plan, request, reference_count: int,
         return None
     must, rng = node.must[0], node.filter[0]
     shoulds = list(node.should)
-    if (not isinstance(must, PPostings) or not must.scoring
+    terms = (PPostings, PTermLane)
+    if (not isinstance(must, terms) or not must.scoring
             or not isinstance(rng, PRange)):
         return None
     for s in shoulds:
-        if not isinstance(s, PPostings) or not s.scoring:
+        if not isinstance(s, terms) or not s.scoring:
             return None
     if len(shoulds) == 2 and shoulds[0].norm_slot != shoulds[1].norm_slot:
         return None  # the C++ models ONE shared should field
     for p in [must] + shoulds:
-        if not plan.array_keys[p.ids_slot].startswith("post."):
+        if (isinstance(p, PPostings)
+                and not plan.array_keys[p.ids_slot].startswith("post.")):
             return None  # phrase/precomputed postings: out of scope
 
     def arr(slot, dt=None):
         a = np.ascontiguousarray(plan.arrays[slot])
         return a.astype(dt, copy=False) if dt is not None else a
+
+    def postings(node):
+        """(ids, tfs) as the C++ reads them; a dense term's tf lane is
+        turned back into its posting list."""
+        if isinstance(node, PPostings):
+            return arr(node.ids_slot), arr(node.tfs_slot)
+        lane = plan.arrays[node.lane_slot]
+        ids = np.flatnonzero(lane).astype(np.int32)
+        return ids, lane[ids].astype(np.int32)
 
     ts_values = arr(rng.values_slot)
     if ts_values.dtype.kind not in "iu" or ts_values.dtype == np.uint64:
@@ -81,13 +92,12 @@ def _native_cpu_bool_range(plan, request, reference_count: int,
     if not rng.hi_incl:
         hi -= 1
 
-    must_ids = arr(must.ids_slot)
-    must_tfs = arr(must.tfs_slot)
+    must_ids, must_tfs = postings(must)
     must_norms = arr(must.norm_slot, np.int32)
     must_idf = float(np.asarray(plan.scalars[must.idf_slot]))
     must_avg = float(np.asarray(plan.scalars[must.avg_len_slot]))
     empty = np.zeros(0, np.int32)
-    s_arrs = [(arr(s.ids_slot), arr(s.tfs_slot)) for s in shoulds]
+    s_arrs = [postings(s) for s in shoulds]
     while len(s_arrs) < 2:
         s_arrs.append((empty, empty))
     if shoulds:
